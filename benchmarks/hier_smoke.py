@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import api
 from repro.core import selector as sel
 from repro.core.comm import Communicator, HierarchicalCommunicator

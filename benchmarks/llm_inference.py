@@ -43,7 +43,7 @@ import time
 
 from repro import configs
 from repro.core import selector as sel
-from repro.roofline.analysis import V5E
+from repro.roofline.analysis import hardware_for
 
 TP = 8
 # paper Fig. 10 batch configurations
@@ -67,15 +67,16 @@ def decode_comm_us(cfg, batch: int, backend: str) -> float:
     return 2 * cfg.n_layers * per
 
 
-def decode_compute_us(cfg, batch: int, seqlen: int) -> float:
-    """Roofline decode step time on 8 chips: weight streaming dominates
-    (memory-bound at small batch) + KV reads."""
+def decode_compute_us(cfg, batch: int, seqlen: int, hw) -> float:
+    """Roofline decode step time on 8 chips of peak table entry ``hw``:
+    weight streaming dominates (memory-bound at small batch) + KV
+    reads."""
     param_bytes = cfg.param_count() * 2 / TP
     kv_bytes = (cfg.n_layers * batch * cfg.n_kv_heads * seqlen
                 * cfg.hd * 2 * 2) / TP
-    mem_s = (param_bytes + kv_bytes) / V5E.hbm_bw
+    mem_s = (param_bytes + kv_bytes) / hw.hbm_bw
     flops = 2 * cfg.param_count() * batch / TP
-    comp_s = flops / V5E.peak_flops
+    comp_s = flops / hw.peak_flops
     return max(mem_s, comp_s) * 1e6
 
 
@@ -336,8 +337,10 @@ def explicit_decode_smoke(tokens=4) -> dict:
 def main(rows=None):
     rows = rows if rows is not None else []
     cfg = configs.get_config("llama2-70b")
+    # an analytic model of a TPU v5e deployment, computed on any host
+    hw = hardware_for("TPU v5 lite")
     for bsz, seqlen in GRID:
-        comp = decode_compute_us(cfg, bsz, seqlen)
+        comp = decode_compute_us(cfg, bsz, seqlen, hw)
         nccl = decode_comm_us(cfg, bsz, "nccl")
         ours = decode_comm_us(cfg, bsz, "mscclpp")
         t_base = comp + nccl
@@ -350,7 +353,7 @@ def main(rows=None):
     # prefill: compute-bound, gain should shrink (paper: <=6%)
     for bsz, seqlen in GRID[:3]:
         flops = 2 * cfg.param_count() * bsz * seqlen / TP
-        comp = flops / V5E.peak_flops * 1e6
+        comp = flops / hw.peak_flops * 1e6
         nbytes = bsz * seqlen * cfg.d_model * 2
         nccl = 2 * cfg.n_layers * (sel.estimate_us("allreduce_ring", TP, nbytes)
                                    + _NCCL_OVERHEAD_US)

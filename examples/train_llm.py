@@ -47,17 +47,6 @@ def main():
                                   n_kv_heads=2, head_dim=32, d_ff=256,
                                   vocab=1024)
     n_params = cfg.param_count()
-
-    # explicit gradient reduction keeps TP under GSPMD while the DP axes
-    # go manual — partial-manual shard_map, which legacy jax lacks. Fall
-    # back to auto there (mirrors the serve engine's graceful fallback)
-    # so the example runs on any container.
-    from repro import compat
-
-    if args.mode == "explicit" and not compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-        print("mode=explicit needs partial-manual shard_map (newer jax); "
-              "falling back to auto")
-        args.mode = "auto"
     print(f"model: {cfg.name}  params≈{n_params/1e6:.0f}M  mode={args.mode}")
 
     devs = jax.devices()[:8]

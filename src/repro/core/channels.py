@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import primitives as prim
-from repro import compat
 
 __all__ = [
     "Protocol",
@@ -159,7 +158,7 @@ class FusedReduceChannel:
 
     def broadcast(self, src_ref, dst_slots_ref, my_id=None) -> None:
         """Push src into `dst_slots_ref[my_id]` on every peer."""
-        num = compat.axis_size(self.axis)
+        num = jax.lax.axis_size(self.axis)
         me = jax.lax.axis_index(self.axis) if my_id is None else my_id
 
         def body(i, _):
@@ -184,7 +183,7 @@ class FusedReduceChannel:
 
     def reduce(self, out_ref, local_ref, slots_ref, my_id=None) -> None:
         """Wait for N-1 pushed chunks, then out = local + sum(slots)."""
-        num = compat.axis_size(self.axis)
+        num = jax.lax.axis_size(self.axis)
         me = jax.lax.axis_index(self.axis) if my_id is None else my_id
 
         def wait_body(i, _):

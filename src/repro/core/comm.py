@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import algorithms as algos
 from repro.core import passes
 from repro.core import selector as sel
@@ -558,10 +557,9 @@ class Communicator:
         self.stats = {"compiles": 0, "hits": 0}
         #: robustness counters (surfaced through Engine.plan_report):
         #: programs verified clean / verification failures seen /
-        #: recompile-once degradations after a failure / pallas->xla
-        #: backend fallbacks
+        #: recompile-once degradations after a failure
         self.health = {"verified": 0, "verify_failures": 0,
-                       "recompiles": 0, "fallbacks": 0}
+                       "recompiles": 0}
 
     # -- configuration -----------------------------------------------------
     def set_tuning_table(self, table: Optional[sel.TuningTable]) -> None:
@@ -588,7 +586,7 @@ class Communicator:
             return n
         if self.n is not None:
             return self.n
-        return compat.axis_size(self.axis)
+        return jax.lax.axis_size(self.axis)
 
     def compile(self, collective: str, shape, dtype, *,
                 algo: Optional[str] = None, backend: Optional[str] = None,
@@ -790,23 +788,13 @@ class Communicator:
         est = link.time_us(
             stats["comm_rounds"] + stats["barriers"], stats[bytes_key],
             extra_syncs=max(0, stats["sync_steps"] - stats["comm_rounds"]))
-        try:
-            executor = _build_executor(prog, self.axis, collective, backend,
-                                       level, n)
-        except Exception as e:
-            if backend != "pallas":
-                raise
-            # graceful degradation: the pallas lowering is the
-            # paper-faithful fast path, the xla lowering runs the same
-            # verified program — serve on it rather than fail
-            self.health["fallbacks"] += 1
-            warnings.warn(
-                f"pallas lowering failed for {collective}/{name} "
-                f"(n={n}): {e} — falling back to the xla backend",
-                stacklevel=3)
+        if backend == "pallas" and not PallasExecutor(prog, self.axis).fits(
+                rows + pad, cols, dtype):
+            # the kernel holds the whole payload in VMEM: plan the same
+            # verified program on the xla lowering (plan.backend says so)
             backend = "xla"
-            executor = _build_executor(prog, self.axis, collective, backend,
-                                       level, n)
+        executor = _build_executor(prog, self.axis, collective, backend,
+                                   level, n)
         return ExecutionPlan(
             collective=collective, algo=name, axis=self.axis, n=n,
             shape=(rows, cols), dtype=dtype, backend=backend,

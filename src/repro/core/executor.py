@@ -51,7 +51,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core import primitives as prim
 from repro.core.channels import MemoryChannel
 from repro.core.dsl import IndexExpr, Instr, Op, Program, full_fanout
@@ -63,6 +62,28 @@ __all__ = ["XlaExecutor", "PallasExecutor", "execute"]
 # cross-round hazard of §2.2.2 'Inflexible Synchronization'); a barrier
 # is auto-inserted if a program has more comm rounds than pairs.
 _NUM_SEM_PAIRS = 4
+
+#: The Pallas kernel keeps its input, output and every local buffer
+#: whole in VMEM. Mosaic's default scoped VMEM limit on TPU v5e is
+#: 16 MiB (its refusal reads "scoped allocation ... limit 16.00M");
+#: ``PallasExecutor.fits`` holds a payload to it so that oversize
+#: payloads are planned on the XLA backend instead of failing to compile.
+VMEM_LIMIT_BYTES = 16 * 2**20
+#: TPU vector tiles are (8 * packing) sublanes x 128 lanes; the executor
+#: pads a payload's columns to a whole number of lanes at dispatch.
+_LANES = 128
+
+
+def _tile_rows(rows: int, itemsize: int) -> int:
+    """Chunk rows padded to a shape Mosaic slices without refusal: a
+    whole number of sublane tiles (8 rows of 32-bit words, packing
+    ``4 // itemsize`` narrower rows into each), or below one tile a
+    power of two of at least one packed row group."""
+    packing = max(1, 4 // itemsize)
+    tile = 8 * packing
+    if rows >= tile:
+        return -(-rows // tile) * tile
+    return max(packing, 1 << (rows - 1).bit_length())
 
 
 # ---------------------------------------------------------------------------
@@ -426,7 +447,7 @@ class XlaExecutor:
             x = inj.on_execute(x)
         p = self.program
         axis = self.axis
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         me = jax.lax.axis_index(axis)
         n_in = p.chunks[p.in_buffer]
         rows = x.shape[0] // n_in
@@ -510,6 +531,22 @@ class PallasExecutor:
         self._prepared: Optional[Tuple[int, dict, dict, dict]] = None
         #: DMA put descriptors issued by the most recent kernel trace
         self.last_trace_descriptors: int = 0
+
+    def vmem_bytes(self, rows: int, cols: int, dtype) -> int:
+        """VMEM one invocation on a ``(rows, cols)`` payload allocates:
+        input, output and local buffers, each chunk at the padded shape
+        the kernel runs at (``_tile_rows`` rows, whole 128-lane
+        columns)."""
+        p = self.program
+        itemsize = np.dtype(dtype).itemsize
+        chunk_rows = _tile_rows(rows // p.chunks[p.in_buffer], itemsize)
+        lanes = -(-cols // _LANES) * _LANES
+        return sum(p.chunks.values()) * chunk_rows * lanes * itemsize
+
+    def fits(self, rows: int, cols: int, dtype) -> bool:
+        """Whether a ``(rows, cols)`` payload lowers within the scoped
+        VMEM limit (see ``VMEM_LIMIT_BYTES``)."""
+        return self.vmem_bytes(rows, cols, dtype) <= VMEM_LIMIT_BYTES
 
     def prepare(self, n: int) -> "PallasExecutor":
         """Prebuild the wait→put-round matching and the per-instruction
@@ -702,7 +739,7 @@ class PallasExecutor:
     def _kernel(self, x_ref, out_ref, locals_refs, bar_sem, *sems):
         p = self.program
         axis = self.axis
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         me = jax.lax.axis_index(axis)
         prim.start_barrier(axis)
 
@@ -797,10 +834,19 @@ class PallasExecutor:
         n_out = p.chunks[p.out_buffer]
         rows = x.shape[0] // n_in
         cols = x.shape[1]
+        # Mosaic refuses slices of a partial lane tile or of a partial
+        # sublane tile: pad each chunk's rows (``_tile_rows``) and the
+        # columns to whole lanes. Every op here moves or adds whole
+        # chunks elementwise, so the zero padding is exact.
+        rpad = _tile_rows(rows, np.dtype(x.dtype).itemsize) - rows
+        cpad = (-cols) % _LANES
+        if rpad or cpad:
+            x = jnp.pad(x.reshape(n_in, rows, cols),
+                        ((0, 0), (0, rpad), (0, cpad)))
         from repro.core import trace as trace_mod
         col = trace_mod.active()
         if col is not None:       # profiler hook (trace time only)
-            col.record(self, n=compat.axis_size(self.axis), chunk_rows=rows,
+            col.record(self, n=jax.lax.axis_size(self.axis), chunk_rows=rows,
                        cols=cols, dtype=np.dtype(x.dtype).name,
                        backend="pallas")
         # every buffer that is neither the kernel input nor output gets
@@ -809,7 +855,7 @@ class PallasExecutor:
         local_names = [b for b in p.chunks
                        if b not in (p.in_buffer, p.out_buffer)]
         scratch_shapes: list[Any] = [
-            pltpu.VMEM((p.chunks[b], rows, cols), x.dtype)
+            pltpu.VMEM((p.chunks[b], rows + rpad, cols + cpad), x.dtype)
             for b in local_names]
         scratch_shapes.append(pltpu.SemaphoreType.REGULAR)
         scratch_shapes += [pltpu.SemaphoreType.DMA] * (2 * _NUM_SEM_PAIRS)
@@ -821,15 +867,16 @@ class PallasExecutor:
 
         out = pl.pallas_call(
             kernel,
-            out_shape=jax.ShapeDtypeStruct((n_out, rows, cols), x.dtype),
+            out_shape=jax.ShapeDtypeStruct(
+                (n_out, rows + rpad, cols + cpad), x.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=scratch_shapes,
             interpret=interpret,
-            compiler_params=compat.CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 collective_id=self.collective_id),
-        )(x.reshape(1, n_in, rows, cols))
-        return out.reshape(n_out * rows, cols)
+        )(x.reshape(1, n_in, rows + rpad, cols + cpad))
+        return out[:, :rows, :cols].reshape(n_out * rows, cols)
 
 
 def execute(program: Program, x: jax.Array, *, axis: str,

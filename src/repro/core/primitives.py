@@ -30,8 +30,6 @@ import jax
 from jax.experimental import pallas as pl  # noqa: F401  (re-exported for users)
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 __all__ = [
     "RemoteCopy",
     "put",
@@ -48,34 +46,13 @@ __all__ = [
 # multi-device TPU kernels on CPU. ``dma_execution_mode='on_wait'`` (the
 # default) exhibits cross-device delivery skew in emulation (documented in
 # DESIGN.md §8); 'eager' executes the DMA at ``start()`` which matches the
-# memory-consistency contract the paper's ``put`` requires. On legacy jax
-# (no ``pltpu.InterpretParams``) these degrade to the generic interpreter,
-# whose discharge rules are already eager — see ``repro.compat``.
-INTERPRET_PARAMS = compat.interpret_params(
+# memory-consistency contract the paper's ``put`` requires.
+INTERPRET_PARAMS = pltpu.InterpretParams(
     dma_execution_mode="eager", detect_races=False
 )
-INTERPRET_PARAMS_RACECHECK = compat.interpret_params(
+INTERPRET_PARAMS_RACECHECK = pltpu.InterpretParams(
     dma_execution_mode="eager", detect_races=True
 )
-
-
-def _legacy_emulation() -> bool:
-    """True when kernels run under the legacy generic interpreter, whose
-    remote-DMA discharge accepts only scalar device ids and whose
-    remote ``semaphore_signal`` is unimplemented."""
-    return compat.LEGACY_INTERPRET and jax.default_backend() != "tpu"
-
-
-def _device_id(mapping: Mapping[str, Any]):
-    """Adapt a ``{axis: index}`` mesh address for the active runtime.
-
-    Real TPU lowering (and the modern interpreter) take the dict form;
-    the legacy interpreter's discharge rule gathers the id with
-    ``all_gather`` and needs the bare index (single-axis meshes only).
-    """
-    if _legacy_emulation() and len(mapping) == 1:
-        return next(iter(mapping.values()))
-    return dict(mapping)
 
 
 @dataclasses.dataclass
@@ -124,7 +101,7 @@ def put(
         dst_ref=dst_ref,
         send_sem=send_sem,
         recv_sem=recv_sem,
-        device_id=_device_id(device_id),
+        device_id=dict(device_id),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     if start:
@@ -148,12 +125,6 @@ def signal(sem, device_id: Mapping[str, Any] | None = None, inc: int = 1) -> Non
     previously-issued DMAs to the same peer (ICI ordering)."""
     if device_id is None:
         pltpu.semaphore_signal(sem, inc)
-    elif _legacy_emulation():
-        # The legacy interpreter has no remote-signal discharge rule.
-        # Its DMAs complete eagerly at start(), so cross-device
-        # ordering never hinges on this signal; waits are pure local
-        # bookkeeping. Dropping the signal is therefore sound there.
-        return
     else:
         pltpu.semaphore_signal(
             sem,
@@ -191,23 +162,14 @@ def wait_recv_into(dst_ref, send_sem, recv_sem, device_id: Mapping[str, Any]) ->
         dst_ref=dst_ref,
         send_sem=send_sem,
         recv_sem=recv_sem,
-        device_id=_device_id(device_id),
+        device_id=dict(device_id),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
     desc.wait_recv()
 
 
 def poll_flag(flag_ref, flag_value, *, index=(0, 0)) -> None:
-    """Spin until ``flag_ref[index] == flag_value`` (LL-protocol recv).
-
-    The poll loop's condition reads a VMEM ref, which the legacy
-    generic interpreter cannot discharge (no ref effects in a while
-    cond) — but there the inline flag has already landed when the put
-    discharged eagerly, so the poll is skipped entirely.
-    """
-    if _legacy_emulation():
-        return
-
+    """Spin until ``flag_ref[index] == flag_value`` (LL-protocol recv)."""
     def cond(_):
         return flag_ref[index] != flag_value
 
@@ -230,23 +192,17 @@ def start_barrier(axis: str | Sequence[str]) -> None:
     (on hardware: not yet entered the kernel; in interpret mode this
     races as a missing-buffer error). The barrier semaphore is the only
     cross-kernel-stable semaphore, hence its use here — requires
-    ``compiler_params=compat.CompilerParams(collective_id=...)``.
+    ``compiler_params=pltpu.CompilerParams(collective_id=...)``.
 
     This is the TPU equivalent of the paper's bootstrap-then-communicate
     contract (§4.1): connections (here: buffer registration) must be
     established before one-sided puts fly.
-
-    Under the legacy generic interpreter this is a no-op: remote DMAs
-    discharge to lockstep SPMD collectives there, so no device can
-    observe a peer that has not "entered the kernel".
     """
-    if _legacy_emulation():
-        return
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     sem = pltpu.get_barrier_semaphore()
     total = 0
     for ax in axes:
-        num = compat.axis_size(ax)
+        num = jax.lax.axis_size(ax)
         me = jax.lax.axis_index(ax)
 
         def _signal_peer(i, _):
@@ -276,18 +232,12 @@ def device_barrier(sem, axis: str | Sequence[str], *, my_id=None) -> None:
     global barrier semaphore, makes back-to-back collective invocations
     race-free (no put can fly into a kernel instance a peer has not yet
     entered).
-
-    No-op under the legacy generic interpreter (remote signals are
-    unimplemented there and its eager lockstep discharge makes the
-    barrier redundant — see ``start_barrier``).
     """
     del my_id
-    if _legacy_emulation():
-        return
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     total = 0
     for ax in axes:
-        num = compat.axis_size(ax)
+        num = jax.lax.axis_size(ax)
         me = jax.lax.axis_index(ax)
 
         def _signal_peer(i, _):

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import comm as comm_lib
-from repro import compat
 
 __all__ = ["moe_layer_ep", "ep_capacity"]
 
@@ -80,7 +79,7 @@ def moe_layer_ep(p, x, cfg, *, axis: str,
     """
     comm = comm if comm is not None else comm_lib.default_communicator(axis)
     b, s, d = x.shape
-    ep = compat.axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     e_total = p["router"].shape[-1]
     e_local = e_total // ep
     k = cfg.moe.top_k

@@ -20,7 +20,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import comm as comm_lib
@@ -127,14 +127,6 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes,
             return params, opt_state, dict(metrics, loss=l)
 
     elif mode == "explicit":
-        from repro import compat
-        if not compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-            # The legacy auto= spelling aborts the whole process inside
-            # XLA's SPMD partitioner — fail loudly and catchably instead.
-            raise NotImplementedError(
-                "mode='explicit' needs partial-manual shard_map "
-                "(jax with shard_map axis_names=); this jax only has the "
-                "legacy auto= spelling, which crashes XLA on this pattern")
         # Gradients are computed per-DP-shard inside a shard_map that is
         # MANUAL over the dp axes (model stays auto/GSPMD for TP), then
         # reduced by OUR collectives: 2PH hierarchical across (pod, data)
@@ -194,13 +186,6 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes,
         out_shardings=(psh, osh, None),
         donate_argnums=donate_argnums,
     ), bspec
-
-
-def _strip_dp(pspecs):
-    """Param specs never include the dp axes; inside shard_map over the
-    full mesh the per-device grad view keeps its model-axis sharding
-    (expressed in the spec) and is replicated over dp."""
-    return pspecs
 
 
 # ---------------------------------------------------------------------------
@@ -378,6 +363,43 @@ class TPDecodeComms:
         return g.reshape(self.tp, b, -1).transpose(1, 0, 2).reshape(b, -1)
 
 
+def _explicit_tp(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
+                 batch: int, kv_lens, kv_quant: bool, comm, plans,
+                 manual_dp: bool, seq_buckets=None):
+    """Set-up shared by the explicit-TP steps: the manual axis set, a
+    spec filter, the explicit param/cache specs and the plan-replay
+    comms. The steps' ``shard_map`` in/out specs may name manual axes
+    only, so ``strip`` removes the axes left to GSPMD
+    (``manual_dp=False``); the body then sees the whole batch along
+    them, and the plans are bucketed for that batch."""
+    ok, why = shd.explicit_decode_supported(cfg, mesh, ax)
+    if not ok:
+        raise ValueError(f"mode='explicit' unsupported here: {why}")
+    dp = _dp_axes(mesh, ax)
+    manual = {ax.model} | (set(dp) if manual_dp else set())
+    auto_axes = [a for a in mesh.axis_names if a not in manual]
+
+    def strip(specs):
+        return functools.reduce(shd.strip_axis, auto_axes, specs)
+
+    tp = int(mesh.shape[ax.model])
+    pspecs_x = shd.explicit_decode_pspecs(cfg, mesh, ax)
+    cspecs_x = shd.explicit_decode_cache_pspecs(
+        cfg, mesh, ax, batch=batch, kv_lens=kv_lens, kv_quant=kv_quant)
+    if comm is None:
+        comm = comm_lib.Communicator(ax.model, n=tp,
+                                     backend=comm_lib.default_backend())
+    if plans is None:
+        b_plan = local_batch(mesh, ax, batch)[0] if manual_dp else batch
+        plans = compile_decode_plans(cfg, comm, batch_local=b_plan, tp=tp,
+                                     seq_buckets=seq_buckets)
+    comms = TPDecodeComms(cfg, ax.model, tp,
+                          hidden_plan=plans["layer_allreduce"],
+                          logits_plan=plans.get("logits_allgather"),
+                          moe_plan=plans.get("moe_alltoall"))
+    return manual, strip, pspecs_x, cspecs_x, comms
+
+
 def make_serve_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
                     batch: int, max_kv: int, donate: bool = True,
                     fsdp: bool = False, kv_quant: bool = False,
@@ -409,9 +431,8 @@ def make_serve_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
       SSM state is the one cache entry that stays model-sharded
       (``sharding.explicit_decode_cache_pspecs``). The DP axes are
       included in the manual set by default (``manual_dp=True``), which
-      keeps the whole step fully manual and therefore runnable on
-      legacy jax. ``manual_dp=False`` leaves the DP axes to GSPMD —
-      partial-manual shard_map, guarded like ``make_train_step``.
+      keeps the whole step fully manual. ``manual_dp=False`` leaves the
+      DP axes to GSPMD (partial-manual shard_map).
 
     ``comm``: the TP :class:`~repro.core.comm.Communicator` owning the
     decode plans (the engine passes its own so init-compiled plans are
@@ -432,7 +453,7 @@ def make_serve_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
                       k_scale=list(cspecs["k"]), v_scale=list(cspecs["v"]))
     dp = _dp_axes(mesh, ax)
     d = dp if len(dp) > 1 else (dp[0] if dp else None)
-    b_local, batch_sharded = local_batch(mesh, ax, batch)
+    _, batch_sharded = local_batch(mesh, ax, batch)
     tok_spec = P(d) if batch_sharded else P(None)
     tsh = NamedSharding(mesh, tok_spec)
 
@@ -456,40 +477,12 @@ def make_serve_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
         raise ValueError(
             "mode='explicit' does not support fsdp: the manual body uses "
             "the explicit-TP param layout, not the ZeRO-3 decoration")
-    ok, why = shd.explicit_decode_supported(cfg, mesh, ax)
-    if not ok:
-        raise ValueError(f"mode='explicit' unsupported here: {why}")
-    manual = {ax.model} | (set(dp) if manual_dp else set())
-    if set(mesh.axis_names) - manual:
-        from repro import compat
-        if not compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-            # The legacy auto= spelling aborts the whole process inside
-            # XLA's SPMD partitioner — fail loudly and catchably instead
-            # (mirrors make_train_step's guard). manual_dp=True needs no
-            # partial-manual support: every mesh axis is manual.
-            raise NotImplementedError(
-                "mode='explicit' with auto (GSPMD) mesh axes needs "
-                "partial-manual shard_map (jax with shard_map "
-                "axis_names=); this jax only has the legacy auto= "
-                "spelling, which crashes XLA on this pattern. Keep "
-                "manual_dp=True so the step is fully manual.")
-
-    tp = int(mesh.shape[ax.model])
-    pspecs_x = shd.explicit_decode_pspecs(cfg, mesh, ax)
     # cache whole along TP — except the hybrid SSM state, which stays
     # model-sharded (each rank carries its d_inner rows)
-    cspecs_x = shd.explicit_decode_cache_pspecs(
-        cfg, mesh, ax, batch=batch, kv_lens=kv_lens, kv_quant=kv_quant)
+    manual, strip, pspecs_x, cspecs_x, comms = _explicit_tp(
+        cfg, mesh, ax, batch=batch, kv_lens=kv_lens, kv_quant=kv_quant,
+        comm=comm, plans=plans, manual_dp=manual_dp)
     csh_x = shd.shardings_for(cspecs_x, mesh)
-    if comm is None:
-        comm = comm_lib.Communicator(ax.model, n=tp,
-                                     backend=comm_lib.default_backend())
-    if plans is None:
-        plans = compile_decode_plans(cfg, comm, batch_local=b_local, tp=tp)
-    comms = TPDecodeComms(cfg, ax.model, tp,
-                          hidden_plan=plans["layer_allreduce"],
-                          logits_plan=plans.get("logits_allgather"),
-                          moe_plan=plans.get("moe_alltoall"))
     logit_spec = P(d if batch_sharded else None, None)
 
     def local_step(params, cache, tokens, pos):
@@ -497,8 +490,8 @@ def make_serve_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
 
     mapped = shard_map(
         local_step, mesh=mesh,
-        in_specs=(pspecs_x, cspecs_x, tok_spec, P()),
-        out_specs=(logit_spec, cspecs_x),
+        in_specs=strip((pspecs_x, cspecs_x, tok_spec, P())),
+        out_specs=strip((logit_spec, cspecs_x)),
         axis_names=manual, check_vma=False)
 
     # Params deliberately carry no jit in_sharding: the engine's arrays
@@ -526,8 +519,7 @@ def _mask_slots(new_cache, old_cache, active):
 
 def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
                     batch: int, max_kv: int, kv_quant: bool = False,
-                    mode: str = "auto", comm=None, plans=None,
-                    manual_dp: bool = True):
+                    mode: str = "auto", comm=None, plans=None):
     """jit'd continuous-batching decode step (the scheduler hot path).
 
     sched_step(params, cache, tokens, pos, active) -> (logits, cache)
@@ -559,7 +551,7 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
     hit counters; §5.2 compile-once contract) instead of compiling its
     own per-bucket family.
     """
-    b_local, batch_sharded = local_batch(mesh, ax, batch)
+    _, batch_sharded = local_batch(mesh, ax, batch)
     if batch_sharded:
         raise ValueError(
             "make_sched_step keeps the batch unsharded (slots live on one "
@@ -592,33 +584,10 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
     if mode != "explicit":
         raise ValueError(mode)
 
-    ok, why = shd.explicit_decode_supported(cfg, mesh, ax)
-    if not ok:
-        raise ValueError(f"mode='explicit' unsupported here: {why}")
-    dp = _dp_axes(mesh, ax)
-    manual = {ax.model} | (set(dp) if manual_dp else set())
-    if set(mesh.axis_names) - manual:
-        from repro import compat
-        if not compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-            raise NotImplementedError(
-                "mode='explicit' with auto (GSPMD) mesh axes needs "
-                "partial-manual shard_map; keep manual_dp=True so the "
-                "step is fully manual (mirrors make_serve_step's guard)")
-
-    tp = int(mesh.shape[ax.model])
-    pspecs_x = shd.explicit_decode_pspecs(cfg, mesh, ax)
-    cspecs_x = shd.explicit_decode_cache_pspecs(
-        cfg, mesh, ax, batch=batch, kv_lens=kv_lens, kv_quant=kv_quant)
+    manual, strip, pspecs_x, cspecs_x, comms = _explicit_tp(
+        cfg, mesh, ax, batch=batch, kv_lens=kv_lens, kv_quant=kv_quant,
+        comm=comm, plans=plans, manual_dp=True)
     csh_x = shd.shardings_for(cspecs_x, mesh)
-    if comm is None:
-        comm = comm_lib.Communicator(ax.model, n=tp,
-                                     backend=comm_lib.default_backend())
-    if plans is None:
-        plans = compile_decode_plans(cfg, comm, batch_local=b_local, tp=tp)
-    comms = TPDecodeComms(cfg, ax.model, tp,
-                          hidden_plan=plans["layer_allreduce"],
-                          logits_plan=plans.get("logits_allgather"),
-                          moe_plan=plans.get("moe_alltoall"))
 
     def local_step(params, cache, tokens, pos, active):
         logits, new_cache = tf.decode_step(params, cfg, cache, tokens, pos,
@@ -627,8 +596,8 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
 
     mapped = shard_map(
         local_step, mesh=mesh,
-        in_specs=(pspecs_x, cspecs_x, P(None), P(None), P(None)),
-        out_specs=(P(None, None), cspecs_x),
+        in_specs=strip((pspecs_x, cspecs_x, P(None), P(None), P(None))),
+        out_specs=strip((P(None, None), cspecs_x)),
         axis_names=manual, check_vma=False)
 
     return jax.jit(
@@ -641,7 +610,7 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
 def make_prefill_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes,
                             *, batch: int, seq: int, max_kv: int,
                             kv_quant: bool = False, mode: str = "auto",
-                            comm=None, plans=None, manual_dp: bool = True):
+                            comm=None, plans=None):
     """jit'd fused-prefill micro-step (the scheduler prefill hot path).
 
     prefill_step(params, cache, tokens, pos, n_tok) -> cache
@@ -674,7 +643,7 @@ def make_prefill_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes,
             f"fused prefill covers the dense, MoE, and hybrid families; "
             f"{cfg.family!r} prefills token-by-token through the decode "
             f"path")
-    b_local, batch_sharded = local_batch(mesh, ax, batch)
+    _, batch_sharded = local_batch(mesh, ax, batch)
     if batch_sharded:
         raise ValueError(
             "make_prefill_sched_step keeps the batch unsharded (slots "
@@ -712,34 +681,10 @@ def make_prefill_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes,
     if mode != "explicit":
         raise ValueError(mode)
 
-    ok, why = shd.explicit_decode_supported(cfg, mesh, ax)
-    if not ok:
-        raise ValueError(f"mode='explicit' unsupported here: {why}")
-    dp = _dp_axes(mesh, ax)
-    manual = {ax.model} | (set(dp) if manual_dp else set())
-    if set(mesh.axis_names) - manual:
-        from repro import compat
-        if not compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-            raise NotImplementedError(
-                "mode='explicit' with auto (GSPMD) mesh axes needs "
-                "partial-manual shard_map; keep manual_dp=True so the "
-                "step is fully manual (mirrors make_serve_step's guard)")
-
-    tp = int(mesh.shape[ax.model])
-    pspecs_x = shd.explicit_decode_pspecs(cfg, mesh, ax)
-    cspecs_x = shd.explicit_decode_cache_pspecs(
-        cfg, mesh, ax, batch=batch, kv_lens=kv_lens, kv_quant=kv_quant)
+    manual, strip, pspecs_x, cspecs_x, comms = _explicit_tp(
+        cfg, mesh, ax, batch=batch, kv_lens=kv_lens, kv_quant=kv_quant,
+        comm=comm, plans=plans, manual_dp=True, seq_buckets=(seq,))
     csh_x = shd.shardings_for(cspecs_x, mesh)
-    if comm is None:
-        comm = comm_lib.Communicator(ax.model, n=tp,
-                                     backend=comm_lib.default_backend())
-    if plans is None:
-        plans = compile_decode_plans(cfg, comm, batch_local=b_local, tp=tp,
-                                     seq_buckets=(seq,))
-    comms = TPDecodeComms(cfg, ax.model, tp,
-                          hidden_plan=plans["layer_allreduce"],
-                          logits_plan=plans.get("logits_allgather"),
-                          moe_plan=plans.get("moe_alltoall"))
 
     def local_step(params, cache, tokens, pos, n_tok):
         return tf.prefill_step(params, cfg, cache, tokens, pos, n_tok,
@@ -747,8 +692,9 @@ def make_prefill_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes,
 
     mapped = shard_map(
         local_step, mesh=mesh,
-        in_specs=(pspecs_x, cspecs_x, P(None, None), P(None), P(None)),
-        out_specs=cspecs_x,
+        in_specs=strip((pspecs_x, cspecs_x, P(None, None), P(None),
+                        P(None))),
+        out_specs=strip(cspecs_x),
         axis_names=manual, check_vma=False)
 
     return jax.jit(

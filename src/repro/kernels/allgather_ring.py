@@ -19,7 +19,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import primitives as prim
 from repro.core.channels import MemoryChannel
 from repro.kernels import comm_utils
-from repro import compat
 
 __all__ = ["all_gather_ring", "ag_ring_kernel"]
 
@@ -27,7 +26,7 @@ __all__ = ["all_gather_ring", "ag_ring_kernel"]
 def ag_ring_kernel(x_ref, out_ref, send_sem, recv_sem, bar_sem, *, axis: str):
     """out_ref: (N, rows, cols) VMEM; x_ref: (1, rows, cols) local shard."""
     prim.start_barrier(axis)
-    num = compat.axis_size(axis)
+    num = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     out_ref[me] = x_ref[0]
 
@@ -62,6 +61,6 @@ def all_gather_ring(x, *, axis: str, axis_size: int, interpret=None):
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.REGULAR],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(collective_id=0),
+        compiler_params=pltpu.CompilerParams(collective_id=0),
     )(x[None])
     return out.reshape(n * rows, cols)

@@ -27,7 +27,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import primitives as prim
 from repro.core.channels import MemoryChannel, Protocol
 from repro.kernels import comm_utils
-from repro import compat
 
 __all__ = ["all_reduce_1pa", "ar_1pa_kernel"]
 
@@ -35,7 +34,7 @@ __all__ = ["all_reduce_1pa", "ar_1pa_kernel"]
 def ar_1pa_kernel(x_ref, flag_val_ref, out_ref, scratch, flags, flag_src,
                   send_sem, recv_sem, bar_sem, *, axis: str, use_ll: bool):
     prim.start_barrier(axis)
-    num = compat.axis_size(axis)
+    num = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     flag_value = flag_val_ref[0]
 
@@ -115,5 +114,5 @@ def all_reduce_1pa(x, *, axis: str, axis_size: int, use_ll: bool = True,
             pltpu.SemaphoreType.REGULAR,
         ],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(collective_id=3),
+        compiler_params=pltpu.CompilerParams(collective_id=3),
     )(x[None], flag_value.reshape(1))

@@ -28,7 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import primitives as prim
 from repro.core.channels import MemoryChannel
 from repro.kernels import comm_utils
-from repro import compat
 
 __all__ = ["all_reduce_2ph"]
 
@@ -41,9 +40,9 @@ def ar_2ph_kernel(x_ref, out_ref, local_scratch, node_scratch,
     out_ref: (L, rows, cols) — fully reduced buffer.
     """
     prim.start_barrier((local_axis, node_axis))
-    lnum = compat.axis_size(local_axis)
+    lnum = jax.lax.axis_size(local_axis)
     lme = jax.lax.axis_index(local_axis)
-    nnum = compat.axis_size(node_axis)
+    nnum = jax.lax.axis_size(node_axis)
     nme = jax.lax.axis_index(node_axis)
 
     # ---- phase 1: ReduceScatter along `local` (all-pairs) ----------------
@@ -144,6 +143,6 @@ def all_reduce_2ph(x, *, local_axis: str, local_size: int,
             pltpu.SemaphoreType.REGULAR,
         ],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(collective_id=5),
+        compiler_params=pltpu.CompilerParams(collective_id=5),
     )(x.reshape(1, lnum, rows, cols))
     return out.reshape(lnum * rows, cols)

@@ -18,7 +18,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import primitives as prim
 from repro.core.channels import MemoryChannel
 from repro.kernels import comm_utils
-from repro import compat
 
 __all__ = ["all_to_all_pallas"]
 
@@ -27,7 +26,7 @@ def a2a_kernel(x_ref, out_ref, send_sem, recv_sem, bar_sem, *, axis: str):
     """x_ref: (1, N, rows, cols); out_ref: (N, rows, cols) with
     out[p] = chunk received from peer p."""
     prim.start_barrier(axis)
-    num = compat.axis_size(axis)
+    num = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     out_ref[me] = x_ref[0, me]
 
@@ -64,6 +63,6 @@ def all_to_all_pallas(x, *, axis: str, axis_size: int, interpret=None):
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.REGULAR],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(collective_id=4),
+        compiler_params=pltpu.CompilerParams(collective_id=4),
     )(x.reshape(1, n, rows, cols))
     return out.reshape(n * rows, cols)

@@ -33,7 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import primitives as prim
 from repro.core.channels import MemoryChannel
 from repro.kernels import comm_utils
-from repro import compat
 
 __all__ = ["allgather_matmul", "ag_matmul_kernel"]
 
@@ -45,7 +44,7 @@ def ag_matmul_kernel(x_ref, w_ref, out_ref, xbuf, send_sem, recv_sem, bar_sem,
     xbuf: (N, rows, K) rotating gather buffer (chunk slots).
     """
     prim.start_barrier(axis)
-    num = compat.axis_size(axis)
+    num = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     _, nxt = comm_utils.ring_neighbors(axis)
     chan = MemoryChannel(axis, nxt, send_sem, recv_sem)
@@ -111,6 +110,6 @@ def allgather_matmul(x, w, *, axis: str, axis_size: int, interpret=None,
             pltpu.SemaphoreType.REGULAR,
         ],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(collective_id=6),
+        compiler_params=pltpu.CompilerParams(collective_id=6),
     )(x[None], w)
     return out.reshape(n * rows, f)

@@ -10,24 +10,30 @@ import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.primitives import INTERPRET_PARAMS
-from repro import compat
 
 __all__ = ["interpret_mode", "on_tpu", "ring_neighbors", "check_2d"]
 
 
 def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+    """True when the kernel is being built for TPU devices: the default
+    backend is a TPU, or the enclosing ``shard_map`` mesh is made of TPU
+    devices (a described topology compiled from a CPU host included)."""
+    if jax.default_backend() == "tpu":
+        return True
+    dev = jax.sharding.get_abstract_mesh().abstract_device
+    return dev is not None and dev.device_kind.startswith("TPU")
 
 
 def interpret_mode():
-    """``interpret=`` argument for pallas_call: False on real TPU,
-    eager-DMA interpreter elsewhere (CPU CI / laptop validation)."""
+    """``interpret=`` argument for pallas_call: False (compiled Mosaic)
+    whenever the kernel targets TPU devices, the eager-DMA interpreter
+    only for CPU meshes (CI / laptop validation)."""
     return False if on_tpu() else INTERPRET_PARAMS
 
 
 def ring_neighbors(axis: str):
     """(prev, next) logical ring neighbors along a mesh axis."""
-    num = compat.axis_size(axis)
+    num = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     return jax.lax.rem(me - 1 + num, num), jax.lax.rem(me + 1, num)
 

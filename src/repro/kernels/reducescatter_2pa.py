@@ -25,7 +25,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import primitives as prim
 from repro.core.channels import MemoryChannel
 from repro.kernels import comm_utils
-from repro import compat
 
 __all__ = ["reduce_scatter_2pa", "all_gather_2pa", "all_reduce_2pa"]
 
@@ -34,7 +33,7 @@ def rs_allpairs_kernel(x_ref, out_ref, scratch, send_sem, recv_sem, bar_sem, *, 
     """x_ref: (1, N, rows, cols) — my contribution to every chunk.
     out_ref: (rows, cols) — reduced chunk owned by me."""
     prim.start_barrier(axis)
-    num = compat.axis_size(axis)
+    num = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
 
     def send_body(i, _):
@@ -65,7 +64,7 @@ def rs_allpairs_kernel(x_ref, out_ref, scratch, send_sem, recv_sem, bar_sem, *, 
 def ag_allpairs_kernel(x_ref, out_ref, send_sem, recv_sem, bar_sem, *, axis: str):
     """x_ref: (1, rows, cols) my chunk; out_ref: (N, rows, cols) gathered."""
     prim.start_barrier(axis)
-    num = compat.axis_size(axis)
+    num = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     out_ref[me] = x_ref[0]
 
@@ -105,7 +104,7 @@ def reduce_scatter_2pa(x, *, axis: str, axis_size: int, interpret=None):
             pltpu.SemaphoreType.REGULAR,
         ],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(collective_id=1),
+        compiler_params=pltpu.CompilerParams(collective_id=1),
     )(x.reshape(1, n, rows, cols))
 
 
@@ -123,7 +122,7 @@ def all_gather_2pa(x, *, axis: str, axis_size: int, interpret=None):
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.REGULAR],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(collective_id=2),
+        compiler_params=pltpu.CompilerParams(collective_id=2),
     )(x[None])
     return out.reshape(n * rows, cols)
 

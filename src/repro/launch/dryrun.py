@@ -189,7 +189,7 @@ def run_cell(arch: str, cell: str, *, multi_pod: bool, mode: str = "auto",
         cost=cost, hlo_text=hlo,
         model_flops=model_flops(cfg, specs["kind"], specs["batch"],
                                 specs["seq"]) / chips,
-        pod_boundary=pod_boundary)
+        hw=roof.hardware_for("TPU v5 lite"), pod_boundary=pod_boundary)
 
     result = {
         "arch": arch, "cell": cell, "mesh": mesh_name,

@@ -11,23 +11,23 @@ virtual-clock request trace::
 
     python -m repro.launch.serve --arch qwen3-1.7b --reduced \\
         --replicas 2 --tp 2 --mode explicit --requests 20
+
+The mesh defaults to this host's devices (TP up to 4, the rest DP). On
+a CPU host, emulate a slice by setting
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before the run.
 """
-import os
+import argparse
+import time
 
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import jax
+import numpy as np
 
-import argparse  # noqa: E402
-import time  # noqa: E402
-
-import jax  # noqa: E402
-import numpy as np  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
-
-from repro import configs  # noqa: E402
-from repro.distributed import sharding as shd  # noqa: E402
-from repro.distributed.step import init_sharded  # noqa: E402
-from repro.serve.engine import Engine, ServeConfig  # noqa: E402
+from repro import configs
+from repro.distributed import sharding as shd
+from repro.distributed.step import init_sharded
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import local_mesh
+from repro.serve.engine import Engine, ServeConfig
 
 
 def main():
@@ -40,8 +40,10 @@ def main():
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--max-kv", type=int, default=256)
     ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--dp", type=int, default=2)
-    ap.add_argument("--tp", type=int, default=4)
+    ap.add_argument("--dp", type=int, default=0,
+                    help="data-parallel size (0: from the device count)")
+    ap.add_argument("--tp", type=int, default=0,
+                    help="tensor-parallel size (0: from the device count)")
     ap.add_argument("--mode", choices=("auto", "explicit"), default="auto",
                     help="decode partitioning: GSPMD (auto) or the "
                          "explicit-TP plan-replay hot path (§5.2)")
@@ -63,6 +65,7 @@ def main():
                     help="router path: where to export/load the shared "
                          "plan-file set (default: a temp dir)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if cfg.family == "encoder":
@@ -73,8 +76,7 @@ def main():
     if args.replicas >= 1:
         return _serve_router(cfg, args)
 
-    mesh = Mesh(np.asarray(jax.devices()[: args.dp * args.tp]).reshape(
-        args.dp, args.tp), ("data", "model"))
+    mesh = local_mesh(args.dp, args.tp)
     params, _ = init_sharded(cfg, mesh, shd.MeshAxes(), jax.random.key(0))
     eng = Engine(cfg, params, mesh,
                  ServeConfig(batch=args.batch, max_kv=args.max_kv,
@@ -113,7 +115,9 @@ def _serve_router(cfg, args):
         cfg, ServeConfig(batch=args.batch, max_kv=args.max_kv,
                          temperature=args.temperature,
                          mode=args.mode, kv_quant=args.kv_quant),
-        n_replicas=args.replicas, tp=args.tp, plan_dir=plan_dir,
+        n_replicas=args.replicas,
+        tp=args.tp or max(len(jax.devices()) // args.replicas, 1),
+        plan_dir=plan_dir,
         mode=args.mode)
 
     rng = np.random.RandomState(args.seed)
@@ -141,7 +145,7 @@ def _serve_router(cfg, args):
     m = router.metrics()
     rep = router.plan_report()
     print(f"arch={cfg.name} router: {args.replicas} replicas x "
-          f"tp={args.tp} modes={rep['modes']} degraded={rep['degraded']} "
+          f"tp={router.replicas[0].eng.mesh.shape['model']} modes={rep['modes']} degraded={rep['degraded']} "
           f"(plans from {plan_dir})")
     print(f"served {m['completed']}/{args.requests} requests "
           f"({m['dropped']} dropped), {m['tokens']} tokens at "

@@ -6,22 +6,16 @@
 Full configs target the production mesh (real TPU pods); ``--reduced``
 runs the smoke-scale variant of the same family on local devices. The
 mesh is (data, model) from --dp/--tp (defaults fit the local device
-count).
+count). On a CPU host, emulate a slice by setting
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before the run.
 """
-import os
+import argparse
 
-if "XLA_FLAGS" not in os.environ:  # local CPU runs emulate a small slice
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
-import argparse  # noqa: E402
-
-import jax  # noqa: E402
-import numpy as np  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
-
-from repro import configs  # noqa: E402
-from repro.train import loop as train_loop  # noqa: E402
-from repro.train import optimizer as opt  # noqa: E402
+from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import local_mesh
+from repro.train import loop as train_loop
+from repro.train import optimizer as opt
 
 
 def main():
@@ -39,19 +33,15 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.reduced:
         cfg = configs.reduced(cfg)
 
-    n_dev = len(jax.devices())
-    dp = args.dp or max(n_dev // (args.tp or 4), 1)
-    tp = args.tp or n_dev // dp
-    assert dp * tp <= n_dev, (dp, tp, n_dev)
-    mesh = Mesh(np.asarray(jax.devices()[: dp * tp]).reshape(dp, tp),
-                ("data", "model"))
+    mesh = local_mesh(args.dp, args.tp)
     print(f"arch={cfg.name} params≈{cfg.param_count()/1e6:.1f}M "
-          f"mesh=({dp},{tp}) mode={args.mode}")
+          f"mesh={tuple(mesh.shape.values())} mode={args.mode}")
 
     res = train_loop.run(
         cfg, mesh,

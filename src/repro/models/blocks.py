@@ -138,6 +138,15 @@ def rope(positions, head_dim: int, theta: float,
     return cos, sin
 
 
+def _max_pos(cfg, window, kv_len: int) -> int:
+    """Bound on the positions a cached attention layer can hold: a
+    global layer writes position ``p`` to cache slot ``p``, so its
+    positions stay below the cache length; a windowed (ring) layer
+    wraps, so only the model's ``max_seq`` bounds it. The RoPE table is
+    a constant of the compiled step, sized by this bound."""
+    return kv_len if window is None else cfg.max_seq
+
+
 def apply_rope(x, cos, sin):
     """x: (..., seq, head_dim); cos/sin: (seq, head_dim/2), or already
     broadcast to ``x.ndim`` (vector-pos decode: (b, 1, 1, head_dim/2),
@@ -334,7 +343,7 @@ def decode_attention(p: Params, x, cache_k, cache_v, pos, cfg,
     q, k_new, v_new = _qkv_proj(p, x, cfg)
     vec = jnp.ndim(pos) > 0                 # per-slot positions (batch,)
     cos, sin = rope(pos if vec else pos[None], hd, cfg.rope_theta,
-                    max_pos=cfg.max_seq)
+                    max_pos=_max_pos(cfg, window, max_kv))
     if vec:
         # (b, hd/2) -> (b, 1, 1, hd/2): each slot rotates at its own pos
         cos, sin = cos[:, None, None, :], sin[:, None, None, :]
@@ -518,7 +527,7 @@ def prefill_attention(p: Params, x, cache_k, cache_v, pos, n_tok, cfg,
     # padded chunk columns may index past a row's real end; the +S head-
     # room keeps their (discarded) lanes off the NaN-poison path
     cos, sin = rope(pmat, hd, cfg.rope_theta,
-                    max_pos=cfg.max_seq + S)                # (b, S, hd/2)
+                    max_pos=_max_pos(cfg, window, kv_len) + S)  # (b,S,hd/2)
     cos, sin = cos[:, None], sin[:, None]                   # (b, 1, S, hd/2)
     q = apply_rope(q, cos, sin)
     k_new = apply_rope(k_new, cos, sin)

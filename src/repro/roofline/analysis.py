@@ -22,7 +22,8 @@ import numpy as np
 
 from repro.roofline import hlo_parse
 
-__all__ = ["HW", "V5E", "collective_bytes", "roofline", "RooflineReport"]
+__all__ = ["HW", "HARDWARE", "hardware_for", "collective_bytes", "roofline",
+           "RooflineReport"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,8 +35,26 @@ class HW:
     dcn_bw: float            # B/s per chip across pods
 
 
-V5E = HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9, ici_links=4,
-         dcn_bw=6.25e9)
+#: Per-chip peaks keyed by ``jax.Device.device_kind``. TPU v5e (JAX
+#: reports it as "TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
+#: 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect
+#: (4 links of 50 GB/s); the 6.25 GB/s per-chip DCN share is this model's
+#: assumption for the multi-pod dry-run, not a published figure.
+HARDWARE = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                      ici_links=4, dcn_bw=6.25e9),
+}
+
+
+def hardware_for(device_kind: str) -> HW:
+    """The peak table entry of a device kind. A kind not in the table is
+    an error: a peak is never assumed for an unknown device."""
+    try:
+        return HARDWARE[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates recorded for device kind {device_kind!r}; "
+            f"known kinds: {sorted(HARDWARE)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -147,7 +166,7 @@ class RooflineReport:
 
 def roofline(*, arch: str, cell: str, mesh_name: str, chips: int,
              cost: dict, hlo_text: str, model_flops: float,
-             pod_boundary: Optional[int] = None, hw: HW = V5E
+             hw: HW, pod_boundary: Optional[int] = None
              ) -> RooflineReport:
     """All three terms from the trip-count-aware HLO analyzer
     (``cost_analysis`` under-counts while bodies — DESIGN.md §8);

@@ -27,10 +27,13 @@ step call is guarded — transient executor failures retry with bounded
 exponential backoff; an optional watchdog (``plan_timeout_s``) bounds
 each step's wall clock; an optional numeric guard
 (``guard_numerics``) rejects non-finite logits; and any unrecovered
-explicit-path failure — including plan-verification failures and
-bucket-overflow errors at trace time — degrades the engine to the
-auto (GSPMD) path and re-runs the step there, so serving continues on
-the safe path rather than crashing or emitting wrong tokens. Health
+explicit-path failure of a step that has already compiled degrades
+the engine to the auto (GSPMD) path and re-runs the step there, so
+serving continues on the safe path rather than crashing or emitting
+wrong tokens. A step that fails to trace or compile (a Mosaic
+refusal, an XLA lowering error, a bucket overflow) is not a fault to
+serve around: the error propagates, so a run that reports the
+explicit path really ran it. Health
 counters (``verified``, ``retries``, ``fallbacks``,
 ``faults_detected``) are surfaced through ``plan_report()``. The
 guards add **zero per-token work on the replay hot path** when the
@@ -194,7 +197,7 @@ class Engine:
                                 batch_local=b_local,
                                 seq_buckets=serve_cfg.prefill_seq_buckets)
                 self.decode_plans = dict(decode_plans)
-            except Exception as e:   # mismatched/incomplete shipped set
+            except ValueError as e:   # mismatched/incomplete shipped set
                 plan_err = e
                 warnings.warn(
                     f"loaded decode-plan set rejected ({e}); serving "
@@ -204,13 +207,16 @@ class Engine:
                 self.decode_plans = compile_decode_plans(
                     cfg, self.comm, batch_local=b_local, tp=tp,
                     seq_buckets=serve_cfg.prefill_seq_buckets)
-            except Exception as e:   # verification / compile failure
+            except ValueError as e:   # plan verification failure
                 plan_err = e
                 warnings.warn(
                     f"decode-plan compilation failed ({e}); serving "
                     f"without plan artifacts", stacklevel=2)
 
         self.mode = mode
+        #: set once step_fn has returned: until then a failure is a
+        #: trace/compile error and propagates (see _run_step)
+        self._step_ran = False
         if mode == "explicit":
             if plan_err is not None:
                 warnings.warn(
@@ -230,11 +236,16 @@ class Engine:
                     self.mode = "auto"
         if self.mode == "auto":
             self.step_fn = self._build_step("auto")
+        self.reset()
+
+    def reset(self) -> None:
+        """Start a new batch: a zeroed KV cache, position 0, no slot
+        active. Compiled steps and plans are kept."""
         self.cache = tf.init_cache(
-            cfg, serve_cfg.batch, serve_cfg.max_kv,
-            dtype=jnp.int8 if serve_cfg.kv_quant else None)
+            self.cfg, self.scfg.batch, self.scfg.max_kv,
+            dtype=jnp.int8 if self.scfg.kv_quant else None)
         self.pos = 0
-        self.active = np.zeros(serve_cfg.batch, bool)
+        self.active = np.zeros(self.scfg.batch, bool)
 
     def _build_step(self, mode: str):
         kw = (dict(comm=self.comm, plans=self.decode_plans or None)
@@ -272,15 +283,24 @@ class Engine:
 
     def _run_step(self, tokens):
         """step_fn with the guardrail ladder: bounded retry with
-        exponential backoff for transient failures; watchdog timeout,
-        numeric guard, and structural plan failures degrade to the
-        auto path and re-run the step there."""
+        exponential backoff for failures, then degradation to the auto
+        path, which re-runs the step; a watchdog timeout or the numeric
+        guard degrades at once. Until the step has run once, an error
+        other than an injected fault or a timeout is a trace/compile
+        failure (a Mosaic refusal, an XLA lowering error): it
+        propagates, never retried or served around."""
         args = (self.params, self.cache, tokens, jnp.int32(self.pos))
         attempt = 0
         while True:
             try:
                 logits, cache = self._dispatch(args)
-            except (faults.FaultInjected, RuntimeError) as e:
+            except TimeoutError as e:
+                self.health["timeouts"] += 1
+                self.health["faults_detected"] += 1
+                return self._fallback_to_auto(str(e), args)
+            except Exception as e:
+                if not (self._step_ran or isinstance(e, faults.FaultInjected)):
+                    raise
                 if attempt < self.scfg.max_retries:
                     attempt += 1
                     self.health["retries"] += 1
@@ -288,16 +308,9 @@ class Engine:
                                * (2 ** (attempt - 1)))
                     continue
                 return self._fallback_to_auto(
-                    f"transient failure persisted through "
-                    f"{attempt} retries: {e}", args)
-            except TimeoutError as e:
-                self.health["timeouts"] += 1
-                self.health["faults_detected"] += 1
-                return self._fallback_to_auto(str(e), args)
-            except (ValueError, NotImplementedError) as e:
-                # structural plan failure at trace time: verification,
-                # bucket overflow, shape/dtype guards
-                return self._fallback_to_auto(f"plan failure: {e}", args)
+                    f"failure persisted through {attempt} retries: {e}",
+                    args)
+            self._step_ran = True
             if self.scfg.guard_numerics:
                 if not bool(jnp.isfinite(logits).all()):
                     self.health["faults_detected"] += 1
@@ -367,11 +380,7 @@ class Engine:
         if a2a is not None:
             # EP dispatch + combine all_to_all per MoE layer
             per_tok += 2 * self.cfg.n_layers * top_plan(a2a).estimate_us
-        health = dict(self.health)
-        health["verified"] = self.comm.health["verified"]
-        health["verify_failures"] = self.comm.health["verify_failures"]
-        health["recompiles"] = self.comm.health["recompiles"]
-        health["fallbacks"] += self.comm.health["fallbacks"]
+        health = dict(self.health, **self.comm.health)
         traces = {
             name: (tr.summary() if (tr := top_plan(p).last_trace)
                    is not None else None)

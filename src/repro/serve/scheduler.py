@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import faults
 from repro.distributed import step as step_mod
 from repro.models import transformer as tf
 
@@ -185,6 +186,8 @@ class Scheduler:
         self.mode = engine.mode
         self._buckets = [b for b in step_mod.slot_buckets(self.max_slots)]
         self._steps: Dict[tuple, Callable] = {}
+        #: step functions that have returned at least once (compiled)
+        self._ran: set = set()
         self.cache = tf.init_cache(
             engine.cfg, self.max_slots, scfg.max_kv,
             dtype=jnp.int8 if scfg.kv_quant else None)
@@ -320,27 +323,40 @@ class Scheduler:
             self.cache = jax.tree.map(
                 lambda a, s: a.at[:, :b].set(s), self.cache, sub)
 
-    def _run(self, b, tokens, pos, active):
-        """One guarded step at bucket ``b``. A failing explicit step
-        degrades the scheduler to auto (rebuilding its bucket steps)
-        and re-runs from the same pre-step state — the scheduler
-        analogue of the engine's fallback ladder; the engine's
-        ``fallbacks`` health counter records it so the router's
-        aggregate shows the degraded replica."""
-        args = (self.eng.params, self._slice(b), jnp.asarray(tokens),
-                jnp.asarray(pos), jnp.asarray(active))
+    def _guarded(self, get_fn, args):
+        """Run one step through the scheduler's fallback ladder. An
+        explicit step that has run before and now fails degrades the
+        scheduler to auto (rebuilding its steps) and re-runs from the
+        same pre-step state — the scheduler analogue of the engine's
+        ladder; the engine's ``fallbacks`` health counter records it so
+        the router's aggregate shows the degraded replica. A step that
+        has never run failed to trace or compile (a Mosaic refusal, an
+        XLA lowering error): that error propagates."""
+        fn = get_fn()
         try:
-            return self._step_fn(b)(*args)
+            out = fn(*args)
         except Exception as e:
-            if self.mode == "auto":
+            if self.mode == "auto" or not (
+                    fn in self._ran or isinstance(e, faults.FaultInjected)):
                 raise
             warnings.warn(
                 f"explicit scheduler step failed ({e}); falling back to "
-                f"auto (GSPMD) for the remainder of serving", stacklevel=2)
+                f"auto (GSPMD) for the remainder of serving", stacklevel=3)
             self.eng.health["fallbacks"] += 1
             self.mode = "auto"
             self._steps.clear()
-            return self._step_fn(b)(*args)
+            self._prefill_steps.clear()
+            self._ran.clear()
+            fn = get_fn()
+            out = fn(*args)
+        self._ran.add(fn)
+        return out
+
+    def _run(self, b, tokens, pos, active):
+        """One guarded step at bucket ``b``."""
+        args = (self.eng.params, self._slice(b), jnp.asarray(tokens),
+                jnp.asarray(pos), jnp.asarray(active))
+        return self._guarded(lambda: self._step_fn(b), args)
 
     def _step_once(self, pred) -> tuple:
         """Run one masked batched step over the active-slot prefix.
@@ -422,20 +438,7 @@ class Scheduler:
                 n_tok[i] = n
         args = (self.eng.params, self._slice(b), jnp.asarray(tokens),
                 jnp.asarray(pos), jnp.asarray(n_tok))
-        try:
-            sub = self._prefill_fn(b, S)(*args)
-        except Exception as e:
-            if self.mode == "auto":
-                raise
-            warnings.warn(
-                f"explicit fused-prefill step failed ({e}); falling back "
-                f"to auto (GSPMD) for the remainder of serving",
-                stacklevel=2)
-            self.eng.health["fallbacks"] += 1
-            self.mode = "auto"
-            self._steps.clear()
-            self._prefill_steps.clear()
-            sub = self._prefill_fn(b, S)(*args)
+        sub = self._guarded(lambda: self._prefill_fn(b, S), args)
         self._merge(sub, b)
         for s, n in zip(self._slots, chunks):
             if n > 0:

@@ -115,3 +115,41 @@ def test_fault_spec_validation():
     with pytest.raises(ValueError, match="static fault"):
         faults.FaultInjector(faults.FaultSpec("drop_put"))
     assert faults.active() is None     # nothing leaks between tests
+
+
+# ---------------------------------------------------------------------------
+# no hiding: a step that fails to compile is an error, not a fault
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def mosaic_refusal(monkeypatch):
+    """Every collective executor raises what a Mosaic refusal raises at
+    compile time (a RuntimeError) while the step traces."""
+    from repro.core import executor
+
+    def refuse(self, x):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(executor.XlaExecutor, "__call__", refuse)
+    monkeypatch.setattr(executor.PallasExecutor, "__call__", refuse)
+
+
+def test_engine_compile_error_propagates(mosaic_refusal):
+    eng, prompts = _tiny_engine("explicit", {})
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        eng.prefill(prompts)
+    assert eng.mode == "explicit"
+    assert eng.health["retries"] == 0 and eng.health["fallbacks"] == 0
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_scheduler_compile_error_propagates(mosaic_refusal, fused):
+    from repro.serve.scheduler import Request, Scheduler
+
+    eng, _ = _tiny_engine("explicit", dict(prefill_seq_buckets=(4,)))
+    sched = Scheduler(eng, fused_prefill=fused)
+    sched.submit(Request(rid=0, prompt=np.arange(1, 7, dtype=np.int32),
+                         max_new_tokens=2))
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        sched.tick()
+    assert sched.mode == "explicit"
+    assert sched.plan_report()["health"]["fallbacks"] == 0

@@ -8,7 +8,7 @@ import pytest
 
 pytest.importorskip("hypothesis")  # not in the minimal CI image
 from hypothesis import given, settings, strategies as st
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dsl import PEER, RANK, Program

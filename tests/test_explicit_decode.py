@@ -2,17 +2,17 @@
 bit-equivalence (dense TP, MoE expert parallelism, hybrid attention+SSM
 head sharding, and the int8 KV cache), plan replay (compile counters
 flat across decode calls), bucketed plan compilation + pad-at-dispatch
-correctness for every padding strategy (rows / tiled / blocks), the
-partial-manual shard_map guard, and graceful auto fallback (rwkv6 —
+correctness for every padding strategy (rows / tiled / blocks),
+partial-manual shard_map, and graceful auto fallback (rwkv6 —
 the one remaining decode family with no explicit path)."""
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat, configs
+from repro import configs
 from repro.core import comm as comm_lib
 from repro.core.comm import BucketedPlan, Communicator
 from repro.distributed import sharding as shd
@@ -400,35 +400,25 @@ def test_bucketed_reduce_scatter_blocks(mesh4):
 # ---------------------------------------------------------------------------
 # guard + graceful fallback satellites
 # ---------------------------------------------------------------------------
-def test_explicit_guard_on_legacy_partial_manual():
-    """manual_dp=False leaves the DP axes to GSPMD — partial-manual
-    shard_map, which legacy jax cannot do: a clear error, not an XLA
-    crash (mirrors make_train_step's guard)."""
-    if compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-        pytest.skip("partial-manual shard_map available: guard inactive")
-    mesh = _mesh((2, 2), ("data", "model"))
-    with pytest.raises(NotImplementedError, match="partial-manual"):
-        step_mod.make_serve_step(_cfg(), mesh, shd.MeshAxes(), batch=4,
-                                 max_kv=16, mode="explicit",
-                                 manual_dp=False)
-
-
-@pytest.mark.skipif(
-    not compat.HAS_PARTIAL_MANUAL_SHARD_MAP,
-    reason="legacy shard_map auto= CHECK-crashes XLA on partial-manual")
 def test_explicit_partial_manual_runs():
-    """Modern jax: DP stays auto (GSPMD), only the TP axis is manual."""
+    """DP stays auto (GSPMD), only the TP axis is manual."""
     from repro.models import transformer as tf
 
     mesh = _mesh((2, 2), ("data", "model"))
     cfg = _cfg()
     params = _params(cfg, mesh)
-    step, _ = step_mod.make_serve_step(
-        cfg, mesh, shd.MeshAxes(), batch=4, max_kv=16, donate=False,
-        mode="explicit", manual_dp=False)
-    cache = tf.init_cache(cfg, 4, 16)
-    logits, _ = step(params, cache, jnp.zeros((4,), jnp.int32), jnp.int32(0))
-    assert np.isfinite(np.asarray(logits)).all()
+    tokens = jnp.arange(4, dtype=jnp.int32)
+    logits = {}
+    for mode, kw in (("auto", {}), ("explicit", dict(manual_dp=False))):
+        step, _ = step_mod.make_serve_step(
+            cfg, mesh, shd.MeshAxes(), batch=4, max_kv=16, donate=False,
+            mode=mode, **kw)
+        logits[mode], _ = step(params, tf.init_cache(cfg, 4, 16), tokens,
+                               jnp.int32(0))
+    assert np.isfinite(np.asarray(logits["explicit"])).all()
+    np.testing.assert_allclose(np.asarray(logits["explicit"]),
+                               np.asarray(logits["auto"]),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_explicit_falls_back_gracefully_for_unsupported_family():

@@ -3,8 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro import compat
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
@@ -35,14 +34,6 @@ def test_all_to_all(mesh8, shape, dtype):
                                np.asarray(want, np.float64), atol=1e-2)
 
 
-# The 2PH kernel issues remote DMAs inside a 2-axis mesh; the legacy
-# generic interpreter only emulates single-axis remote copies.
-_needs_multiaxis = pytest.mark.skipif(
-    not compat.HAS_MULTIAXIS_REMOTE_DMA,
-    reason="legacy pallas interpreter cannot emulate multi-axis remote DMA")
-
-
-@_needs_multiaxis
 @pytest.mark.parametrize("rows_per_chunk", [8, 16])
 def test_all_reduce_2ph(mesh2x4, rows_per_chunk):
     nn, ln = mesh2x4.shape["node"], mesh2x4.shape["local"]
@@ -63,7 +54,6 @@ def test_all_reduce_2ph(mesh2x4, rows_per_chunk):
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-3, atol=1e-5)
 
 
-@_needs_multiaxis
 def test_all_reduce_2ph_twice(mesh2x4):
     """Back-to-back invocations in one jit must not race (exit barrier)."""
     nn, ln = 2, 4

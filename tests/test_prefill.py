@@ -215,3 +215,33 @@ def test_fused_prefill_rejects_unusable_seq_buckets():
     eng2 = Engine(eng.cfg, eng.params, eng.mesh, scfg, mode="auto")
     with pytest.raises(ValueError, match="no usable prefill sequence"):
         Scheduler(eng2, fused_prefill=True)
+
+
+def test_step_rope_table_is_sized_by_the_cache():
+    """A global attention layer writes position p to cache slot p, so
+    the RoPE table the decode and fused-prefill steps embed as a constant
+    covers the cache, not the model's max_seq: at max_seq=131072 the
+    table alone would be 16 MiB per program (and a full-width step's
+    executable too large for a compile cache)."""
+    cfg = dataclasses.replace(configs.reduced(configs.get_config("qwen3-1.7b")),
+                              max_seq=131_072)
+    mesh = _mesh((1, 1), ("data", "model"))
+    params = jax.eval_shape(
+        lambda: step_mod.init_sharded(cfg, mesh, shd.MeshAxes(),
+                                      jax.random.key(0))[0])
+    from repro.models import transformer as tf
+
+    cache = jax.eval_shape(lambda: tf.init_cache(cfg, BATCH, 64))
+    vec = jax.ShapeDtypeStruct((BATCH,), np.int32)
+    decode, _ = step_mod.make_sched_step(cfg, mesh, shd.MeshAxes(),
+                                         batch=BATCH, max_kv=64)
+    prefill, _ = step_mod.make_prefill_sched_step(
+        cfg, mesh, shd.MeshAxes(), batch=BATCH, seq=8, max_kv=64)
+    texts = [
+        decode.lower(params, cache, vec, vec,
+                     jax.ShapeDtypeStruct((BATCH,), np.bool_)).as_text(),
+        prefill.lower(params, cache,
+                      jax.ShapeDtypeStruct((BATCH, 8), np.int32), vec,
+                      vec).as_text()]
+    for text in texts:
+        assert "131072x" not in text and len(text) < 4 * 2**20

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.roofline import analysis, hlo_parse
 
 
@@ -14,7 +13,7 @@ def _mesh4():
     # Auto is the modern default; legacy jax has no axis_types at all.
     axis_types = (jax.sharding.AxisType.Auto,) \
         if hasattr(jax.sharding, "AxisType") else None
-    return compat.make_mesh((4,), ("x",), axis_types=axis_types)
+    return jax.make_mesh((4,), ("x",), axis_types=axis_types)
 
 
 def test_dot_flops_exact():
@@ -77,16 +76,23 @@ ENTRY %main (p: f32[256]) -> f32[256] {
 
 
 def test_roofline_report_terms():
+    hw = analysis.hardware_for("TPU v5 lite")
     rep = analysis.RooflineReport(
         arch="a", cell="c", mesh="m", chips=256,
         hlo_flops=1e15, hlo_bytes=1e12, coll_ici_bytes=1e11,
         coll_dcn_bytes=0.0, model_flops=8e14,
-        compute_s=1e15 / analysis.V5E.peak_flops,
-        memory_s=1e12 / analysis.V5E.hbm_bw,
-        collective_s=1e11 / (analysis.V5E.ici_bw * analysis.V5E.ici_links))
+        compute_s=1e15 / hw.peak_flops,
+        memory_s=1e12 / hw.hbm_bw,
+        collective_s=1e11 / (hw.ici_bw * hw.ici_links))
     assert rep.dominant == "compute"
     assert 0 < rep.roofline_fraction <= 1
     assert rep.useful_flop_ratio == pytest.approx(0.8)
+
+
+def test_hardware_table_is_keyed_by_device_kind():
+    assert analysis.hardware_for("TPU v5 lite").peak_flops == 197e12
+    with pytest.raises(ValueError, match="no peak rates"):
+        analysis.hardware_for("cpu")
 
 
 def test_nested_scan_multiplies():

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import algorithms as algos
 from repro.core import simulate, trace
 from repro.core import verify as verify_mod

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh
 
-from repro import compat, configs
+from repro import configs
 from repro.distributed import sharding as shd
 from repro.train import checkpoint as ckpt
 from repro.train import compression as comp
@@ -33,12 +33,6 @@ def test_loss_decreases_auto(tmp_path):
     assert np.isfinite(res["losses"]).all()
 
 
-_needs_partial_manual = pytest.mark.skipif(
-    not compat.HAS_PARTIAL_MANUAL_SHARD_MAP,
-    reason="legacy shard_map auto= CHECK-crashes XLA on partial-manual")
-
-
-@_needs_partial_manual
 def test_explicit_mode_matches_auto():
     """The paper-technique DP path must be numerically equivalent."""
     mesh = _mesh((2, 4), ("data", "model"))
@@ -50,7 +44,6 @@ def test_explicit_mode_matches_auto():
     np.testing.assert_allclose(r1["losses"], r2["losses"], rtol=2e-3, atol=1e-4)
 
 
-@_needs_partial_manual
 def test_explicit_hierarchical_two_axis():
     """2-axis DP: grads reduced by the 2PH program across (pod, data)."""
     mesh = _mesh((2, 2, 2), ("pod", "data", "model"))
