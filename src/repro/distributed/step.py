@@ -510,11 +510,13 @@ def _mask_slots(new_cache, old_cache, active):
     """Per-slot cache select for the scheduler step: inactive slots keep
     their old cache rows bit-exactly (the computed updates for those
     rows are discarded). Every decode-cache leaf carries the batch at
-    axis 1 — ``(groups, batch, ...)``, see ``transformer.init_cache``."""
+    axis 1 — ``(groups, batch, ...)``, see ``transformer.init_cache``.
+    Scoped ``mask_slots``: a profile charges this whole-cache pass to it."""
     def sel(new, old):
         m = active.reshape((1, active.shape[0]) + (1,) * (new.ndim - 2))
         return jnp.where(m, new, old)
-    return jax.tree.map(sel, new_cache, old_cache)
+    with jax.named_scope("mask_slots"):
+        return jax.tree.map(sel, new_cache, old_cache)
 
 
 def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
@@ -567,16 +569,18 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
                       k_scale=list(cspecs["k"]), v_scale=list(cspecs["v"]))
     tsh = NamedSharding(mesh, P(None))
 
+    # Named ``step_sched`` (program ``jit_step_sched``) so that a profile
+    # tells it from the fused-prefill step.
     if mode == "auto":
         csh = shd.shardings_for(cspecs, mesh)
 
-        def step(params, cache, tokens, pos, active):
+        def step_sched(params, cache, tokens, pos, active):
             logits, new_cache = tf.decode_step(params, cfg, cache,
                                                tokens, pos)
             return logits, _mask_slots(new_cache, cache, active)
 
         return jax.jit(
-            step,
+            step_sched,
             in_shardings=(psh, csh, tsh, tsh, tsh),
             out_shardings=(None, csh),
         ), cspecs
@@ -589,13 +593,13 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
         comm=comm, plans=plans, manual_dp=True)
     csh_x = shd.shardings_for(cspecs_x, mesh)
 
-    def local_step(params, cache, tokens, pos, active):
+    def step_sched(params, cache, tokens, pos, active):
         logits, new_cache = tf.decode_step(params, cfg, cache, tokens, pos,
                                            comms=comms)
         return logits, _mask_slots(new_cache, cache, active)
 
     mapped = shard_map(
-        local_step, mesh=mesh,
+        step_sched, mesh=mesh,
         in_specs=strip((pspecs_x, cspecs_x, P(None), P(None), P(None))),
         out_specs=strip((P(None, None), cspecs_x)),
         axis_names=manual, check_vma=False)
@@ -666,14 +670,16 @@ def make_prefill_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes,
     tsh = NamedSharding(mesh, P(None))
     tok2 = NamedSharding(mesh, P(None, None))
 
+    # Named ``step_prefill`` (program ``jit_step_prefill``), apart from
+    # the decode step's ``step_sched``.
     if mode == "auto":
         csh = shd.shardings_for(cspecs, mesh)
 
-        def step(params, cache, tokens, pos, n_tok):
+        def step_prefill(params, cache, tokens, pos, n_tok):
             return tf.prefill_step(params, cfg, cache, tokens, pos, n_tok)
 
         return jax.jit(
-            step,
+            step_prefill,
             in_shardings=(psh, csh, tok2, tsh, tsh),
             out_shardings=csh,
         ), cspecs
@@ -686,12 +692,12 @@ def make_prefill_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes,
         comm=comm, plans=plans, manual_dp=True, seq_buckets=(seq,))
     csh_x = shd.shardings_for(cspecs_x, mesh)
 
-    def local_step(params, cache, tokens, pos, n_tok):
+    def step_prefill(params, cache, tokens, pos, n_tok):
         return tf.prefill_step(params, cfg, cache, tokens, pos, n_tok,
                                comms=comms)
 
     mapped = shard_map(
-        local_step, mesh=mesh,
+        step_prefill, mesh=mesh,
         in_specs=strip((pspecs_x, cspecs_x, P(None, None), P(None),
                         P(None))),
         out_specs=strip(cspecs_x),

@@ -352,10 +352,6 @@ def decode_attention(p: Params, x, cache_k, cache_v, pos, cfg,
 
     # ring-buffer update for windowed layers, linear for global layers
     slot = pos % max_kv if window is not None else pos
-    # vector pos writes via a per-row one-hot mask (b, 1, max_kv, 1):
-    # dynamic_update needs one index, every slot has its own
-    wmask = ((jnp.arange(max_kv)[None, :] == slot[:, None])
-             [:, None, :, None] if vec else None)
 
     def _write(cache, scales, val):
         v = val[:, :, 0]
@@ -377,8 +373,14 @@ def decode_attention(p: Params, x, cache_k, cache_v, pos, cfg,
             scales, sc.astype(scales.dtype), slot, axis=2)
         return cache, scales
 
-    cache_k, k_scale = _write(cache_k, k_scale, k_new)
-    cache_v, v_scale = _write(cache_v, v_scale, v_new)
+    # scoped ``cache_write``: a profile charges the write to it
+    with jax.named_scope("cache_write"):
+        # vector pos writes via a per-row one-hot mask (b, 1, max_kv, 1):
+        # dynamic_update needs one index, every slot has its own
+        wmask = ((jnp.arange(max_kv)[None, :] == slot[:, None])
+                 [:, None, :, None] if vec else None)
+        cache_k, k_scale = _write(cache_k, k_scale, k_new)
+        cache_v, v_scale = _write(cache_v, v_scale, v_new)
 
     g = nh // nkv
     if head_offset is not None:

@@ -30,10 +30,18 @@ therefore bit-identical no matter which other requests it shares
 steps with — the scheduler batches for throughput without changing a
 single emitted token vs. a sequential single-request run.
 
-Virtual time: the scheduler never reads a wall clock. ``tick(now)``
-takes the caller's clock (the load generator charges each tick
-``step_s * (1 + micro_steps)``), so traces replay exactly and TTFT /
-throughput metrics are reproducible to the bit.
+Virtual time: by default the scheduler never reads a wall clock.
+``tick(now)`` takes the caller's clock (the load generator charges each
+tick ``step_s * (1 + micro_steps)``), so traces replay exactly and TTFT
+/ throughput metrics are reproducible to the bit. A caller serving on
+the wall clock passes ``clock=``: emissions are then stamped when their
+tokens have been sampled, not at the tick's start.
+
+Tracing: every tick writes ``sched.*`` host spans
+(``jax.profiler.TraceAnnotation``) into an active profiler trace, on
+the device's clock, so each device idle gap can be named by what the
+host was doing (docs/serving.md "Tracing the scheduler"). With no trace
+active a span costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -58,6 +66,14 @@ __all__ = ["Request", "Emission", "TickInfo", "Scheduler",
 #: recurrent state is excluded — see Scheduler._seed_prefix)
 _PC_KINDS = ("k", "v", "k_scale", "v_scale")
 
+_Span = jax.profiler.TraceAnnotation
+
+
+def _rids(slots) -> str:
+    """Request ids as a span argument, so one request's spans share it.
+    Space-joined: the trace's argument encoding splits on commas."""
+    return " ".join(str(s.req.rid) for s in slots)
+
 
 @dataclasses.dataclass
 class Request:
@@ -79,7 +95,7 @@ class Emission:
     rid: int
     token: int
     done: bool
-    t: float                           # virtual emission time
+    t: float             # emission time (virtual, or ``clock()`` if given)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,6 +172,10 @@ class Scheduler:
       compacts into the freed row (one cache-row copy — an exact
       permutation, so streams are unaffected) to keep active slots a
       contiguous prefix and the step bucket minimal.
+    * **Time stamps** — admission uses the tick's ``now``. First-token,
+      finish and emission times are ``now`` too, unless ``clock`` (a
+      function returning the caller's time) is given: then they are
+      read from it once a tick's tokens are sampled.
 
     The batch must not be DP-sharded: one scheduler owns one replica;
     scale-out across replicas is :class:`repro.serve.router.Router`.
@@ -163,8 +183,10 @@ class Scheduler:
 
     def __init__(self, engine, *, max_slots: Optional[int] = None,
                  prefill_chunk: int = 4, fused_prefill: bool = False,
-                 queue_limit: Optional[int] = None, prefix_cache=None):
+                 queue_limit: Optional[int] = None, prefix_cache=None,
+                 clock: Optional[Callable[[], float]] = None):
         self.eng = engine
+        self.clock = clock
         scfg = engine.scfg
         self.max_slots = int(max_slots or scfg.batch)
         if not 1 <= self.max_slots <= scfg.batch:
@@ -556,12 +578,19 @@ class Scheduler:
         ``now`` (default: the internal clock): admit, run the chunked-
         prefill micro-steps, run the combined step, sample/stream, and
         recycle completed slots."""
+        with _Span("sched.tick"):
+            return self._tick(now)
+
+    def _tick(self, now: Optional[float]) -> TickInfo:
         now = self._now if now is None else float(now)
         if now < self._now:
             raise ValueError(f"virtual clock moved backwards "
                              f"({now} < {self._now})")
         self._now = now
-        admitted = self._admit(now)
+        with _Span("sched.admit") as span:
+            admitted = self._admit(now)
+            if admitted and _Span.is_enabled():
+                span.set_metadata(rids=_rids(self._slots[-admitted:]))
         emissions: List[Emission] = []
         micro = 0
         bucket = 0
@@ -569,38 +598,57 @@ class Scheduler:
             def prefilling(s):
                 return s.consumed < len(s.req.prompt) - 1
 
+            # the slot count, and so the bucket, holds until the releases
+            b = self._bucket(len(self._slots))
             while micro < self.prefill_chunk - 1 and \
                     any(prefilling(s) for s in self._slots):
-                if self.fused_prefill:
-                    self._prefill_once()
-                else:
-                    self._step_once(prefilling)
+                with _Span("sched.step", kind="prefill", bucket=b):
+                    if self.fused_prefill:
+                        self._prefill_once()
+                    else:
+                        self._step_once(prefilling)
                 micro += 1
-            logits, bucket = self._step_once(lambda s: True)
-            rows = np.asarray(logits, np.float32)
-            done_idx = []
-            for i, s in enumerate(self._slots):
-                if s.consumed < len(s.req.prompt):
-                    continue        # prompt not done (chunk budget spent)
-                tok = self._sample_row(s, rows[i])
-                s.last_token = tok
-                s.emitted += 1
+            with _Span("sched.step", kind="combined", bucket=b):
+                logits, bucket = self._step_once(lambda s: True)
+            with _Span("sched.sync"):
+                rows = np.asarray(logits, np.float32)
+            new_tokens = []     # (slot index, slot, token, finished)
+            with _Span("sched.sample") as span:
+                for i, s in enumerate(self._slots):
+                    if s.consumed < len(s.req.prompt):
+                        continue    # prompt not done (chunk budget spent)
+                    tok = self._sample_row(s, rows[i])
+                    s.last_token = tok
+                    s.emitted += 1
+                    if s.emitted == 1:
+                        # first sampled token: the cache row holds exactly
+                        # the prompt tape — index it for prefix reuse
+                        self._snapshot_prefix(s, i)
+                    self.streams[s.req.rid].append(tok)
+                    fin = (tok == self.eos_id
+                           or s.emitted >= s.req.max_new_tokens)
+                    new_tokens.append((i, s, tok, fin))
+                if _Span.is_enabled():
+                    span.set_metadata(rows=len(new_tokens), sampled=sum(
+                        1 for _, s, _, _ in new_tokens
+                        if s.req.temperature > 0))
+            t = now if self.clock is None else float(self.clock())
+            done = []
+            for i, s, tok, fin in new_tokens:
                 if s.t_first is None:
-                    s.t_first = now
-                    # first sampled token: the cache row holds exactly
-                    # the prompt tape — index it for prefix reuse
-                    self._snapshot_prefix(s, i)
-                self.streams[s.req.rid].append(tok)
-                fin = (tok == self.eos_id
-                       or s.emitted >= s.req.max_new_tokens)
-                emissions.append(Emission(s.req.rid, tok, fin, now))
+                    s.t_first = t
+                emissions.append(Emission(s.req.rid, tok, fin, t))
                 if fin:
-                    self._finish(s, now)
-                    done_idx.append(i)
-            # release in descending index order so each compaction's
-            # "last slot" is still correct
-            for i in sorted(done_idx, reverse=True):
-                self._release(i)
+                    self._finish(s, t)
+                    done.append(i)
+            with _Span("sched.release") as span:
+                if done and _Span.is_enabled():
+                    span.set_metadata(rids=_rids(self._slots[i]
+                                                 for i in done))
+                # release in descending index order so each compaction's
+                # "last slot" is still correct
+                for i in sorted(done, reverse=True):
+                    self._release(i)
         self._ticks += 1
         self._micro_total += micro
         return TickInfo(now=now, admitted=admitted, micro_steps=micro,
