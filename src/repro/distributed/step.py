@@ -508,15 +508,19 @@ def make_serve_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
 
 def _mask_slots(new_cache, old_cache, active):
     """Per-slot cache select for the scheduler step: inactive slots keep
-    their old cache rows bit-exactly (the computed updates for those
-    rows are discarded). Every decode-cache leaf carries the batch at
-    axis 1 — ``(groups, batch, ...)``, see ``transformer.init_cache``.
-    Scoped ``mask_slots``: a profile charges this whole-cache pass to it."""
+    their old recurrent state (the hybrid ``ssm`` leaves, rwkv6's state)
+    bit-exactly; the computed updates for those rows are discarded. The
+    attention leaves pass through: the step never wrote an inactive
+    row's K/V. Every decode-cache leaf carries the batch at axis 1 —
+    ``(groups, batch, ...)``, see ``transformer.init_cache``. Scoped
+    ``mask_slots``: a profile charges this pass to it."""
     def sel(new, old):
         m = active.reshape((1, active.shape[0]) + (1,) * (new.ndim - 2))
         return jnp.where(m, new, old)
     with jax.named_scope("mask_slots"):
-        return jax.tree.map(sel, new_cache, old_cache)
+        return {n: leaf if n in tf.KV_LEAVES
+                else jax.tree.map(sel, leaf, old_cache[n])
+                for n, leaf in new_cache.items()}
 
 
 def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
@@ -535,7 +539,13 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
     * ``active`` is a ``(batch,)`` bool mask — inactive slots' cache
       rows pass through bit-exactly, so chunked-prefill micro-steps can
       advance a subset of slots while decode slots hold still, and
-      freed slots carry stale state harmlessly.
+      freed slots carry stale state harmlessly. The step writes K/V
+      only for active slots, one row each in place
+      (``transformer.decode_step``); ``_mask_slots`` selects the
+      recurrent state, the only leaves computed for every row.
+
+    The cache is not donated here: the caller that owns it decides
+    (``Scheduler`` donates in auto mode, on the engine's rule).
 
     Because every per-row op in the decode step is row-independent
     (einsums contract within a row, softmax/rms_norm are per-row, and
@@ -576,7 +586,7 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
 
         def step_sched(params, cache, tokens, pos, active):
             logits, new_cache = tf.decode_step(params, cfg, cache,
-                                               tokens, pos)
+                                               tokens, pos, active=active)
             return logits, _mask_slots(new_cache, cache, active)
 
         return jax.jit(
@@ -595,7 +605,7 @@ def make_sched_step(cfg: ModelConfig, mesh: Mesh, ax: shd.MeshAxes, *,
 
     def step_sched(params, cache, tokens, pos, active):
         logits, new_cache = tf.decode_step(params, cfg, cache, tokens, pos,
-                                           comms=comms)
+                                           comms=comms, active=active)
         return logits, _mask_slots(new_cache, cache, active)
 
     mapped = shard_map(

@@ -304,17 +304,25 @@ def _chunked_attn(q, k, v, cfg, *, window: Optional[int], chunk: int):
     return out.astype(q.dtype)
 
 
-def decode_attention(p: Params, x, cache_k, cache_v, pos, cfg,
+def decode_attention(p: Params, x, cache_k, cache_v, layer, pos, cfg,
                      *, window: Optional[int], k_scale=None, v_scale=None,
-                     head_offset=None):
+                     head_offset=None, active=None):
     """One-token decode with KV cache.
 
-    x: (batch, 1, d_model); cache_k/v: (batch, nkv, max_kv, hd);
+    x: (batch, 1, d_model); cache_k/v (and the int8 scales): the stacked
+    ``(groups, batch, nkv, max_kv, hd)`` leaves that the layer scan
+    carries, of which this layer owns group index ``layer``: the new
+    token lands there, in place, and attention reads that group's slice.
     pos: current position — a scalar shared by the whole batch, or a
     ``(batch,)`` vector of per-slot positions (continuous batching:
     every slot decodes at its own depth; RoPE, the cache write, and the
     validity mask are then applied per row). Returns (out, new_k,
-    new_v[, new_k_scale, new_v_scale]).
+    new_v[, new_k_scale, new_v_scale]), the stacked leaves.
+
+    The write scatters one ``hd`` row per (batch row, head) at that
+    row's slot (a scalar ``pos`` is every row's). ``active``: a
+    ``(batch,)`` bool mask; a row where it is False is not written (its
+    index is pushed out of range and the update dropped).
 
     int8 KV quantization (§Perf hillclimb C): when the cache dtype is
     int8, new tokens are written as round(x/s·127) with a per-(batch,
@@ -337,7 +345,7 @@ def decode_attention(p: Params, x, cache_k, cache_v, pos, cfg,
     b, _, d = x.shape
     hd = cfg.hd
     nh, nkv = padded_heads(cfg)
-    max_kv = cache_k.shape[2]
+    max_kv = cache_k.shape[-2]
     quant = cache_k.dtype == jnp.int8
 
     q, k_new, v_new = _qkv_proj(p, x, cfg)
@@ -353,34 +361,35 @@ def decode_attention(p: Params, x, cache_k, cache_v, pos, cfg,
     # ring-buffer update for windowed layers, linear for global layers
     slot = pos % max_kv if window is not None else pos
 
+    def _put(cache, v):
+        # one row per slot and head: (b, nkv, hd) at [layer, b, n, row[b]]
+        idx = (layer, jnp.arange(b)[:, None],
+               jnp.arange(cache.shape[2])[None, :], row[:, None])
+        return cache.at[idx].set(v, mode="drop")
+
     def _write(cache, scales, val):
         v = val[:, :, 0]
         if not quant:
-            if vec:
-                return jnp.where(wmask, v[:, :, None, :], cache), scales
-            return jax.lax.dynamic_update_index_in_dim(
-                cache, v, slot, axis=2), scales
+            return _put(cache, v), scales
         sc = (jnp.max(jnp.abs(v.astype(jnp.float32)),
                       axis=-1, keepdims=True) / 127.0 + 1e-8)
         qv = jnp.clip(jnp.round(v.astype(jnp.float32) / sc),
                       -127, 127).astype(jnp.int8)
-        if vec:
-            return (jnp.where(wmask, qv[:, :, None, :], cache),
-                    jnp.where(wmask, sc.astype(scales.dtype)[:, :, None, :],
-                              scales))
-        cache = jax.lax.dynamic_update_index_in_dim(cache, qv, slot, axis=2)
-        scales = jax.lax.dynamic_update_index_in_dim(
-            scales, sc.astype(scales.dtype), slot, axis=2)
-        return cache, scales
+        return _put(cache, qv), _put(scales, sc.astype(scales.dtype))
 
     # scoped ``cache_write``: a profile charges the write to it
     with jax.named_scope("cache_write"):
-        # vector pos writes via a per-row one-hot mask (b, 1, max_kv, 1):
-        # dynamic_update needs one index, every slot has its own
-        wmask = ((jnp.arange(max_kv)[None, :] == slot[:, None])
-                 [:, None, :, None] if vec else None)
+        # an inactive row's index is out of range, so its write drops
+        row = jnp.broadcast_to(slot, (b,))
+        if active is not None:
+            row = jnp.where(active, row, max_kv)
         cache_k, k_scale = _write(cache_k, k_scale, k_new)
         cache_v, v_scale = _write(cache_v, v_scale, v_new)
+    new_caches = ((cache_k, cache_v, k_scale, v_scale) if quant
+                  else (cache_k, cache_v))
+    cache_k, cache_v = cache_k[layer], cache_v[layer]
+    if quant:
+        k_scale, v_scale = k_scale[layer], v_scale[layer]
 
     g = nh // nkv
     if head_offset is not None:
@@ -388,9 +397,7 @@ def decode_attention(p: Params, x, cache_k, cache_v, pos, cfg,
                                     window=window, head_offset=head_offset,
                                     slot=slot, g=g,
                                     k_scale=k_scale, v_scale=v_scale)
-        if quant:
-            return out, cache_k, cache_v, k_scale, v_scale
-        return out, cache_k, cache_v
+        return (out,) + new_caches
     q = q.reshape(b, nkv, g, 1, hd)
     if quant:
         # int8 dot in bf16 compute (C2: halves the dequant materialization
@@ -428,9 +435,7 @@ def decode_attention(p: Params, x, cache_k, cache_v, pos, cfg,
         head_mask = (jnp.arange(nh) < cfg.n_heads).astype(out.dtype)
         out = out * head_mask[None, :, None, None]
     ret = jnp.einsum("bnsh,nhd->bsd", out, p["wo"])
-    if quant:
-        return ret, cache_k, cache_v, k_scale, v_scale
-    return ret, cache_k, cache_v
+    return (ret,) + new_caches
 
 
 def _decode_attn_tp_shard(p: Params, q, cache_k, cache_v, pos, cfg,

@@ -29,7 +29,7 @@ from repro.models.blocks import rms_norm
 from repro.models.config import ModelConfig
 
 __all__ = ["init_params", "forward", "logits_fn", "loss_fn", "init_cache",
-           "decode_step", "prefill_step", "layer_windows"]
+           "decode_step", "prefill_step", "layer_windows", "KV_LEAVES"]
 
 
 # ---------------------------------------------------------------------------
@@ -179,6 +179,11 @@ def loss_fn(params, cfg: ModelConfig, batch, *, remat_policy: str = "none"):
 # ---------------------------------------------------------------------------
 # decode
 # ---------------------------------------------------------------------------
+#: decode-cache leaves that hold one entry per token position (attention
+#: K/V and their int8 scales), as against recurrent state (SSM, RWKV)
+KV_LEAVES = ("k", "v", "k_scale", "v_scale")
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_kv: int, dtype=None):
     """Decode cache pytree.
 
@@ -212,12 +217,21 @@ def init_cache(cfg: ModelConfig, batch: int, max_kv: int, dtype=None):
     return cache
 
 
-def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, comms=None):
+def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, comms=None,
+                active=None):
     """tokens: (b,) int32 (or (b, d) embeddings); pos: scalar int32, or
     a (b,) int32 vector of per-slot positions (continuous batching —
     see ``blocks.decode_attention``; the SSM/RWKV recurrences are
     position-free, so only attention branches on it).
     Returns (logits (b, vocab) f32, new cache).
+
+    The attention leaves (K, V and the int8 scales) ride through the
+    layer scan as its carry, and each layer writes its new token into
+    them at its group index (``blocks.decode_attention``), so XLA
+    updates the cache in place instead of restacking every layer's
+    cache as the scan's output. ``active`` (a ``(b,)`` bool mask): rows
+    where it is False write no K/V. Recurrent state (SSM, RWKV) is
+    still computed for every row; the caller selects it.
 
     ``comms`` — the per-layer TP/EP communication hook of the explicit
     decode path (``repro.distributed.step.TPDecodeComms``). When given,
@@ -264,26 +278,24 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, comms=None):
         h = rms_norm(x, params["ln_f"], cfg.norm_eps)
         return logits_fn(params, cfg, h)[:, 0], new_state
 
-    quant = "k_scale" in cache
+    kv_names = [n for n in KV_LEAVES if n in cache]
 
-    def body(x, scanned):
-        gp_list, ck, cv, sst, ksc, vsc = scanned
-        new_k, new_v, new_s, new_ksc, new_vsc = [], [], [], [], []
+    def body(carry, scanned):
+        x, kv = carry
+        gp_list, g, sst = scanned
+        new_kv = {n: [] for n in kv_names}
+        new_s = []
         for i, win in enumerate(wins):
             lp = gp_list[i]
             h = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
             ho = (comms.head_offset(lp["attn"]["wq"].shape[-2])
                   if comms is not None else None)
-            if quant:
-                att, k_upd, v_upd, ks_upd, vs_upd = blocks.decode_attention(
-                    lp["attn"], h, ck[i], cv[i], pos, cfg, window=win,
-                    k_scale=ksc[i], v_scale=vsc[i], head_offset=ho)
-                new_ksc.append(ks_upd)
-                new_vsc.append(vs_upd)
-            else:
-                att, k_upd, v_upd = blocks.decode_attention(
-                    lp["attn"], h, ck[i], cv[i], pos, cfg, window=win,
-                    head_offset=ho)
+            att, *upd = blocks.decode_attention(
+                lp["attn"], h, kv["k"][i], kv["v"][i], g, pos, cfg,
+                window=win, head_offset=ho, active=active,
+                **{n: kv[n][i] for n in kv_names[2:]})       # int8 scales
+            for n, u in zip(kv_names, upd):
+                new_kv[n].append(u)
             if cfg.family == "hybrid":
                 if comms is not None:
                     # SSM runs on this shard's d_inner rows; its w_out
@@ -317,22 +329,16 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, comms=None):
                 if comms is not None:
                     mlp_out = comms.hidden(mlp_out)  # down-proj partial
                 x = x + mlp_out
-            new_k.append(k_upd)
-            new_v.append(v_upd)
-        return x, (new_k, new_v, new_s, new_ksc, new_vsc)
+        return (x, new_kv), new_s
 
-    sst = cache.get("ssm", [jnp.zeros((n_groups(cfg), 1)) for _ in wins])
-    dummy = [jnp.zeros((n_groups(cfg), 1)) for _ in wins]
-    ksc = cache.get("k_scale", dummy)
-    vsc = cache.get("v_scale", dummy)
-    x, (nk, nv, ns, nksc, nvsc) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"], sst, ksc, vsc))
-    new_cache = dict(cache, k=nk, v=nv)
+    groups = n_groups(cfg)
+    sst = cache.get("ssm", [jnp.zeros((groups, 1)) for _ in wins])
+    kv = {n: cache[n] for n in kv_names}
+    (x, kv), ns = jax.lax.scan(body, (x, kv),
+                               (params["layers"], jnp.arange(groups), sst))
+    new_cache = dict(cache, **kv)
     if "ssm" in cache:
         new_cache["ssm"] = ns
-    if quant:
-        new_cache["k_scale"] = nksc
-        new_cache["v_scale"] = nvsc
     h = rms_norm(x, params["ln_f"], cfg.norm_eps)
     if comms is not None:
         return comms.logits(params, h), new_cache

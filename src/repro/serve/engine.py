@@ -176,7 +176,7 @@ class Engine:
         # exact-replay recovery (re-running a detected-bad step from its
         # pre-step state) needs the inputs alive after the call, so the
         # detecting guards disable donation; the default path keeps it
-        self._donate = not (serve_cfg.guard_numerics
+        self.donates = not (serve_cfg.guard_numerics
                             or serve_cfg.plan_timeout_s is not None)
         self._pool: Optional[concurrent.futures.ThreadPoolExecutor] = None
 
@@ -252,7 +252,7 @@ class Engine:
               if mode == "explicit" else {})
         fn, _ = make_serve_step(
             self.cfg, self.mesh, self.ax, batch=self.scfg.batch,
-            max_kv=self.scfg.max_kv, donate=self._donate, mode=mode,
+            max_kv=self.scfg.max_kv, donate=self.donates, mode=mode,
             kv_quant=self.scfg.kv_quant, **kw)
         return fn
 

@@ -62,10 +62,6 @@ from repro.models import transformer as tf
 __all__ = ["Request", "Emission", "TickInfo", "Scheduler",
            "AsyncServeEngine"]
 
-#: cache-leaf kinds the prefix cache snapshots (pure-attention tape;
-#: recurrent state is excluded — see Scheduler._seed_prefix)
-_PC_KINDS = ("k", "v", "k_scale", "v_scale")
-
 _Span = jax.profiler.TraceAnnotation
 
 
@@ -330,6 +326,11 @@ class Scheduler:
                 self.eng.cfg, self.eng.mesh, self.eng.ax, batch=b,
                 max_kv=self.eng.scfg.max_kv,
                 kv_quant=self.eng.scfg.kv_quant, mode=self.mode, **kw)
+            if self.mode == "auto" and self.eng.donates:
+                # the engine's rule: no recovery re-run here needs the
+                # pre-step cache, so the step takes it and updates it in
+                # place (an explicit step is re-run by ``_guarded``)
+                fn = jax.jit(fn, donate_argnums=(1,))
             self._steps[key] = fn
         return fn
 
@@ -502,7 +503,7 @@ class Scheduler:
         self._prefix["tokens_reused"] += L
         slot.pc_handle = handle
         upd = {}
-        for kind in _PC_KINDS:
+        for kind in tf.KV_LEAVES:
             if kind in self.cache:
                 upd[kind] = [
                     leaf.at[:, i, :, :L].set(
@@ -524,7 +525,7 @@ class Scheduler:
                 or self.prefix_cache.match(prompt) >= plen):
             return
         segs = {}
-        for kind in _PC_KINDS:
+        for kind in tf.KV_LEAVES:
             if kind in self.cache:
                 for j, leaf in enumerate(self.cache[kind]):
                     segs[f"{kind}{j}"] = np.ascontiguousarray(

@@ -5,7 +5,8 @@
   bucket), ``sched.sync``, ``sched.sample`` and ``sched.release``, in
   that order, with the request ids that tie a request's spans together.
 * Spans and scopes change no emitted token, and the scopes change the
-  optimized step program only in its metadata.
+  optimized step program only in its metadata: ``cache_write`` in every
+  step, ``mask_slots`` where a step selects recurrent state (hybrid).
 * ``Scheduler(clock=)`` stamps first token, finish and emission times
   from the caller's clock once the tick's tokens are sampled.
 """
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import Mesh
 
 from benchmarks import loadgen
+from repro import configs
 from repro.distributed import sharding as shd
 from repro.distributed import step as step_mod
 from repro.models import transformer as tf
@@ -145,12 +147,30 @@ def _optimized_hlo(eng, b, scoped: bool):
     return compiled.as_text(), module.to_string(opts)
 
 
-def test_scopes_change_only_metadata(eng):
+@pytest.fixture(scope="module")
+def hybrid_eng():
+    cfg = configs.reduced(configs.get_config("hymba-1.5b"))
+    ax = shd.MeshAxes()
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                (ax.data[0], ax.model))
+    params, _ = step_mod.init_sharded(cfg, mesh, ax, jax.random.key(0))
+    return Engine(cfg, params, mesh,
+                  ServeConfig(batch=BATCH, max_kv=64, mode="auto"), ax=ax)
+
+
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_scopes_change_only_metadata(request, family):
+    """Every step scopes its row write ``cache_write``; ``mask_slots``
+    scopes the select of recurrent state, which only the hybrid step
+    has: the dense step writes K/V in place and selects nothing."""
+    eng = request.getfixturevalue("eng" if family == "dense"
+                                  else "hybrid_eng")
     text, stripped = _optimized_hlo(eng, BATCH, scoped=True)
     _, stripped_plain = _optimized_hlo(eng, BATCH, scoped=False)
     assert stripped == stripped_plain
     assert "HloModule jit_step_sched" in text
-    assert "/mask_slots/" in text and "/cache_write/" in text
+    assert "/cache_write/" in text
+    assert ("/mask_slots/" in text) == (family == "hybrid")
 
 
 def test_clock_stamps_emissions_after_sampling(eng):
